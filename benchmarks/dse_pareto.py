@@ -157,4 +157,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.caches import enable_compile_cache
+    enable_compile_cache()
     main()

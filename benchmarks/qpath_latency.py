@@ -17,8 +17,9 @@ topologies and batch buckets:
   sub-byte packed weight buffers unpacked in-VMEM.
 
 Each topology also reports the *resident streamed weight bytes* per working
-point (``PackedWeights.view_bytes``): W4 <= 0.55x and W2 <= 0.30x of W8 is
-the packed-storage acceptance band, plus the ``im2col_bytes`` scratch term
+point (``PackedWeights.view_bytes``): at most bits/8 of W8 plus one 128-row
+K-padding tile per tensor (``PackedWeights.view_bytes_bound``) is the
+packed-storage acceptance band, plus the ``im2col_bytes`` scratch term
 (:func:`repro.launch.roofline.im2col_scratch_bytes`): the patch tensor the
 im2col conv lowering would materialize at that batch, previously invisible
 in every byte model.
@@ -141,6 +142,8 @@ def run(full: bool = True) -> List[Dict]:
         qpath = qw.qpath
         assert qw8.int8_act_on, "D8 point must enable the integer hot path"
         storage = {f"w{b}_bytes": qw.packed.view_bytes(b) for b in (8, 4, 2)}
+        storage.update({f"w{b}_bytes_bound": qw.packed.view_bytes_bound(b)
+                        for b in (4, 2)})
         has_dw = any(n.op in DW_OPS for n in res8.graph.nodes)
         fns = [fq, pk, i8]
         if has_dw:
@@ -188,8 +191,8 @@ def evaluate(rows: List[Dict]) -> Dict:
     target = 1.3 if row["qpath"] == "pallas" else 0.9
     packed_ok = row["speedup"] >= target
     int8_ok = row["int8act_vs_packed"] >= 0.9
-    bytes_ok = (row["w4_bytes"] <= 0.55 * row["w8_bytes"]
-                and row["w2_bytes"] <= 0.30 * row["w8_bytes"])
+    bytes_ok = all(row[f"w{b}_bytes"] <= row[f"w{b}_bytes_bound"]
+                   for b in (4, 2))
     dw_row = next((r for r in rows if r["topology"] == DW_CRITERION_TOPOLOGY
                    and r["batch"] == CRITERION_BATCH), None)
     if dw_row is None or "dw_speedup" not in dw_row:
@@ -238,4 +241,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.caches import enable_compile_cache
+    enable_compile_cache()
     main()

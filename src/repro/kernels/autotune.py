@@ -2,11 +2,12 @@
 
 Timed tiling picks are two-level cached: each kernel family keeps its own
 in-process L1 dict, and compiled-backend timings persist here to ONE JSON
-file (``~/.cache/repro/autotune.json``, override with
-``REPRO_AUTOTUNE_CACHE=<path>``, disable with ``REPRO_AUTOTUNE_CACHE=off``)
-so tuning survives across processes.  Keys are family-prefixed strings
-(``"512:384:..."`` for qmatmul, ``"dw:..."`` for the depthwise conv kernels)
-and values are integer block tuples of *family-specific arity*.
+file (``<repo>/.cache/autotune.json`` beside the compilation cache of
+:mod:`repro.caches`, override with ``REPRO_AUTOTUNE_CACHE=<path>``, disable
+with ``REPRO_AUTOTUNE_CACHE=off``) so tuning survives across processes.
+Keys are family-prefixed strings (``"512:384:..."`` for qmatmul, ``"dw:..."``
+for the depthwise conv kernels) and values are integer block tuples of
+*family-specific arity*.
 
 The file carries an explicit schema version::
 
@@ -23,6 +24,8 @@ from __future__ import annotations
 import json
 import os
 from typing import Dict, Optional, Tuple
+
+from repro.caches import CACHE_ROOT
 
 # bump on any key-format or block-tuple-arity change; mismatched (or
 # pre-versioned) files are discarded and retuned
@@ -61,8 +64,7 @@ def autotune_cache_path() -> Optional[str]:
     """Resolved disk-cache path, or None when persistence is disabled."""
     p = os.environ.get(AUTOTUNE_CACHE_ENV)
     if p is None:
-        return os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                            "autotune.json")
+        return str(CACHE_ROOT / "autotune.json")
     p = p.strip()
     if p.lower() in ("", "0", "off", "none"):
         return None

@@ -23,7 +23,9 @@ Three orthogonal extensions make this the *fully-integer* engine:
   stores the int8 *code* instead of the dequantized value, so codes — not
   floats — flow through the inter-layer FIFO to the next kernel.
 
-Block shapes are MXU-aligned (multiples of 128 on M/N; 128 lanes on K).
+Block shapes are MXU-aligned: multiples of 128 on M/N, and on K a multiple
+of 128 lanes per activation view (``128 * r`` on the packed path), which the
+TPU compiler requires of every block's last dimension.
 """
 from __future__ import annotations
 
@@ -105,13 +107,16 @@ def qgemm_kernel(*refs, bits: int, nk: int, has_bias: bool, relu: bool,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # the integer path keeps both MXU operands int8 (int32 accumulation):
+    # the W8 codes, the truncated W4/W2 views (multiples of the step inside
+    # [-128, 112]) and the unpacked q fields all fit int8 exactly
     if r == 1:
         if int8_act:
-            w = w_ref[...].astype(jnp.int32)
+            w = w_ref[...]
             if bits < 8:
                 # same round-half-even rule as ptq.derive_view (bit-exact)
-                w = _truncate(w.astype(jnp.float32), bits).astype(jnp.int32)
-            acc_ref[...] += jax.lax.dot(xs[0][...].astype(jnp.int32), w,
+                w = _truncate(w.astype(jnp.float32), bits).astype(jnp.int8)
+            acc_ref[...] += jax.lax.dot(xs[0][...], w,
                                         preferred_element_type=jnp.int32)
         else:
             w = _truncate(w_ref[...].astype(jnp.float32), bits)
@@ -122,7 +127,7 @@ def qgemm_kernel(*refs, bits: int, nk: int, has_bias: bool, relu: bool,
         if int8_act:
             for x_ref, q in zip(xs, fields):
                 acc_ref[...] += jax.lax.dot(
-                    x_ref[...].astype(jnp.int32), q,
+                    x_ref[...], q.astype(jnp.int8),
                     preferred_element_type=jnp.int32)
         else:
             for x_ref, q in zip(xs, fields):
@@ -166,9 +171,9 @@ def build_call(M: int, K: int, N: int, *, bits: int, int8_act: bool,
         assert act_qt[1] >= -128 and act_qt[2] <= 127, \
             f"act_qt {act_qt} does not fit int8 codes"
     bm, bn, bk = min(bm, M), min(bn, N), min(bk, K)
-    if packed and bk % r:
-        bk = max(r, bk - bk % r)
     assert M % bm == 0 and N % bn == 0 and K % bk == 0, (M, K, N, bm, bn, bk)
+    # each activation view's block is (bm, bk/r): whole 128-lane tiles
+    assert bk % (128 * r) == 0, f"bk={bk} is not a multiple of 128*{r}"
     nk = K // bk
     grid = (M // bm, N // bn, nk)
 

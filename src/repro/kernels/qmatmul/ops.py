@@ -1,23 +1,24 @@
 """jit'd public wrappers for the quantized matmul kernel.
 
-``qmatmul(x, codes, scale, bits=…)`` handles arbitrary leading batch dims,
-pads M/K/N up to MXU-aligned tiles, and falls back to the jnp oracle for
-shapes too small to tile (CPU smoke paths).  ``qgemm`` is the float-activation
+``qmatmul(x, codes, scale, bits=…)`` handles arbitrary leading batch dims
+and pads M/K/N up to MXU-aligned tiles, so every shape — a batch of one
+included — runs the kernel.  ``qgemm`` is the float-activation
 writer entry point: bias + ReLU + activation fake-quant fused into the kernel
 epilogue.  ``qmatmul_int8_act`` is the *fully-integer* entry point: the
 activation operand is the producer FIFO's int8 codes + a power-of-two scale,
 MACs run in int32, and ``out_code=True`` re-quantizes the output to the
 consumer's int8 code in the same epilogue — codes, not floats, flow between
 layers.  Both accept ``packed=True`` to stream split-row sub-byte W4/W2
-weight buffers (:func:`repro.quant.pack.pack_rows`) unpacked in-VMEM.
+weight buffers (:func:`repro.quant.pack.pack_rows`) unpacked in-VMEM; the
+packed path pads K to ``pack_align(bits)`` (128 lanes per packed activation
+view) so every activation block is a whole number of lane tiles.
 
 All entry points share backend-aware ``interpret`` selection (compiled on
-TPU, jnp-ref fallback off-TPU) and a block-size autotune cache keyed on the
-padded problem.  The autotune cache is two-level: the in-process dict is L1,
-and timed results persist to a JSON file (``~/.cache/repro/autotune.json``,
-override with ``REPRO_AUTOTUNE_CACHE=<path>``, disable with
-``REPRO_AUTOTUNE_CACHE=off``) so compiled-backend tuning survives across
-processes.
+TPU, interpret mode off-TPU; ``use_kernel=None`` runs the jnp reference
+off-TPU) and a block-size autotune cache keyed on the padded problem.  The
+autotune cache is two-level: the in-process dict is L1, and timed results
+persist to a JSON file (:func:`repro.kernels.autotune.autotune_cache_path`)
+so compiled-backend tuning survives across processes.
 """
 from __future__ import annotations
 
@@ -33,7 +34,7 @@ from repro.kernels.qmatmul.kernel import (ActQt, build_call, DEFAULT_BM,
                                           DEFAULT_BN, DEFAULT_BK)
 from repro.kernels.qmatmul.ref import (qgemm_ref, qmatmul_int8_act_ref,
                                        qmatmul_ref)
-from repro.quant.pack import unpack_rows
+from repro.quant.pack import pack_align, unpack_rows
 
 _MIN_TILE = 128
 
@@ -85,8 +86,25 @@ def _disk_put(key, blocks: Tuple[int, int, int]) -> None:
     autotune.disk_put(_disk_key(key), blocks)
 
 
-def _default_blocks(M: int, K: int, N: int) -> Tuple[int, int, int]:
-    return min(DEFAULT_BM, M), min(DEFAULT_BN, N), min(DEFAULT_BK, K)
+def _fit(dim: int, want: int, align: int) -> int:
+    """Largest multiple of ``align`` that divides ``dim`` (itself a multiple
+    of ``align``) and is no larger than ``max(want, align)``."""
+    b = max(want - want % align, align)
+    while dim % b:
+        b -= align
+    return b
+
+
+def _k_align(bits: int, packed: bool) -> int:
+    """K alignment of a padded problem: one lane tile, or one lane tile per
+    packed activation view (``pack_align``) on the sub-byte path."""
+    return pack_align(bits) if packed else _MIN_TILE
+
+
+def _default_blocks(M: int, K: int, N: int,
+                    k_align: int = _MIN_TILE) -> Tuple[int, int, int]:
+    return (_fit(M, DEFAULT_BM, _MIN_TILE), _fit(N, DEFAULT_BN, _MIN_TILE),
+            _fit(K, DEFAULT_BK, k_align))
 
 
 def _time_call(call, args, iters: int = 3) -> float:
@@ -122,17 +140,20 @@ def pick_blocks(M: int, K: int, N: int, bits: int, interpret: bool,
                 packed: bool = False) -> Tuple[int, int, int]:
     """(bm, bn, bk) for an M×K×N problem at a working point.
 
-    All dims are already padded to multiples of ``_MIN_TILE``.  Results are
-    cached per (M, K, N, bits, int8_act, packed, interpret); the timing pass
-    runs on synthetic concrete data, so it is safe to call at trace time
-    inside an outer jit.  Lookup order: in-process dict, then the on-disk
+    M and N are already padded to multiples of ``_MIN_TILE``, K to
+    ``_k_align(bits, packed)``; every candidate keeps ``bk`` a multiple of
+    that alignment so each packed activation view is whole lane tiles.
+    Results are cached per (M, K, N, bits, int8_act, packed, interpret); the
+    timing pass runs on synthetic concrete data, so it is safe to call at
+    trace time inside an outer jit.  Lookup order: in-process dict, then the on-disk
     cache (compiled-backend entries only), then a timing sweep whose result
     is written through to both."""
     key = (M, K, N, bits, int8_act, packed, interpret)
     hit = _BLOCK_CACHE.get(key)
     if hit is not None:
         return hit
-    default = _default_blocks(M, K, N)
+    kal = _k_align(bits, packed)
+    default = _default_blocks(M, K, N, kal)
     if interpret:
         _BLOCK_CACHE[key] = default
         return default
@@ -144,7 +165,7 @@ def pick_blocks(M: int, K: int, N: int, bits: int, interpret: bool,
     cands = {default}
     for bm, bn, bk in _CANDIDATE_BLOCKS:
         bm, bn, bk = min(bm, M), min(bn, N), min(bk, K)
-        if M % bm == 0 and N % bn == 0 and K % bk == 0 and bk % r == 0:
+        if M % bm == 0 and N % bn == 0 and K % bk == 0 and bk % kal == 0:
             cands.add((bm, bn, bk))
     if len(cands) == 1:
         _BLOCK_CACHE[key] = default
@@ -182,16 +203,17 @@ def qmatmul(x, codes, scale, *, bits: int = 8,
     K, N = codes.shape
     x2 = x.reshape(-1, K)
     M = x2.shape[0]
-    if not use_kernel or min(M, K, N) < 8:
+    if not use_kernel:
         y = qmatmul_ref(x2, codes, scale, bits, out_dtype=x.dtype)
         return y.reshape(*lead, N)
     interp = resolve_interpret(interpret)
     xp = _pad_to(_pad_to(x2, _MIN_TILE, 0), _MIN_TILE, 1)
     cp = _pad_to(_pad_to(codes, _MIN_TILE, 0), _MIN_TILE, 1)
     sp = _pad_to(scale.reshape(1, -1).astype(jnp.float32), _MIN_TILE, 1)
-    call = build_call(xp.shape[0], xp.shape[1], cp.shape[1], bits=bits,
-                      int8_act=False, bm=min(bm, xp.shape[0]),
-                      bn=min(bn, cp.shape[1]), bk=min(bk, xp.shape[1]),
+    (Mp, Kp), Np = xp.shape, cp.shape[1]
+    call = build_call(Mp, Kp, Np, bits=bits, int8_act=False,
+                      bm=_fit(Mp, bm, _MIN_TILE), bn=_fit(Np, bn, _MIN_TILE),
+                      bk=_fit(Kp, bk, _MIN_TILE),
                       out_dtype=x.dtype, interpret=interp)
     y = call(xp.astype(jnp.bfloat16), cp, sp)[:M, :N]
     return y.reshape(*lead, N)
@@ -209,11 +231,11 @@ def qgemm(x, codes, scale, bias=None, *, bits: int = 8, relu: bool = False,
     hot-path op.
 
     x: (..., K) float; codes: (K, N) int8 master — or, with ``packed=True``,
-    the split-row sub-byte buffer (K'/r, N) uint8 where K' is K padded to the
-    tile size (:func:`repro.quant.pack.pack_rows`); scale: (N,) f32; bias:
-    (N,) or None.  ``use_kernel=None`` auto-selects: the compiled Pallas
-    kernel on TPU, the jnp reference (which XLA constant-folds into a plain
-    matmul when codes are trace constants) elsewhere.  ``act_qt`` is the
+    the split-row sub-byte buffer (K'/r, N) uint8 where K' is K padded to
+    ``pack_align(bits)`` (:func:`repro.quant.pack.pack_rows`); scale: (N,)
+    f32; bias: (N,) or None.  ``use_kernel=None`` auto-selects: the compiled
+    Pallas kernel on TPU, the jnp reference (which XLA constant-folds into a
+    plain matmul when codes are trace constants) elsewhere.  ``act_qt`` is the
     consumer-side fixed-point activation quant ``(frac, qmin, qmax)``,
     applied inside the kernel epilogue instead of as a separate round/clip
     op per FIFO."""
@@ -229,12 +251,13 @@ def qgemm(x, codes, scale, bias=None, *, bits: int = 8, relu: bool = False,
     interp = resolve_interpret(interpret)
     if use_kernel is None:
         use_kernel = not interp
-    if not use_kernel or min(M, K, N) < 8:
+    if not use_kernel:
         c = unpack_rows(codes, bits)[:K] if packed else codes
         y = qgemm_ref(x2, c, scale, bias, bits=bits, relu=relu,
                       act_qt=act_qt, out_dtype=x.dtype)
         return y.reshape(*lead, N)
-    xp = _pad_to(_pad_to(x2, _MIN_TILE, 0), _MIN_TILE, 1)
+    kal = _k_align(bits, packed)
+    xp = _pad_to(_pad_to(x2, _MIN_TILE, 0), kal, 1)
     Mp, Kp = xp.shape
     if packed:
         assert codes.shape[0] * r == Kp, (
@@ -257,7 +280,8 @@ def qgemm(x, codes, scale, bias=None, *, bits: int = 8, relu: bool = False,
         args.append(_pad_to(bias.reshape(1, -1).astype(jnp.float32),
                             _MIN_TILE, 1))
     call = build_call(Mp, Kp, Np, bits=bits, int8_act=False,
-                      bm=min(bm, Mp), bn=min(bn, Np), bk=min(bk, Kp),
+                      bm=_fit(Mp, bm, _MIN_TILE), bn=_fit(Np, bn, _MIN_TILE),
+                      bk=_fit(Kp, bk, kal),
                       out_dtype=x.dtype, interpret=interp,
                       has_bias=bias is not None, relu=relu, act_qt=act_qt,
                       packed=packed)
@@ -301,13 +325,14 @@ def qmatmul_int8_act(x_codes, x_scale, codes, scale, bias=None, *,
     interp = resolve_interpret(interpret)
     if use_kernel is None:
         use_kernel = not interp
-    if not use_kernel or min(M, K, N) < 8:
+    if not use_kernel:
         c = unpack_rows(codes, bits)[:K] if packed else codes
         y = qmatmul_int8_act_ref(x2, xs, c, scale, bits, bias=bias, relu=relu,
                                  act_qt=act_qt, out_code=out_code,
                                  out_dtype=out_dtype)
         return y.reshape(*lead, N)
-    xp = _pad_to(_pad_to(x2, _MIN_TILE, 0), _MIN_TILE, 1)
+    kal = _k_align(bits, packed)
+    xp = _pad_to(_pad_to(x2, _MIN_TILE, 0), kal, 1)
     Mp, Kp = xp.shape
     if packed:
         assert codes.shape[0] * r == Kp, (
@@ -336,7 +361,8 @@ def qmatmul_int8_act(x_codes, x_scale, codes, scale, bias=None, *,
         args.append(_pad_to(bias.reshape(1, -1).astype(jnp.float32),
                             _MIN_TILE, 1))
     call = build_call(Mp, Kp, Np, bits=bits, int8_act=True,
-                      bm=min(bm, Mp), bn=min(bn, Np), bk=min(bk, Kp),
+                      bm=_fit(Mp, bm, _MIN_TILE), bn=_fit(Np, bn, _MIN_TILE),
+                      bk=_fit(Kp, bk, kal),
                       out_dtype=out_dtype, interpret=interp,
                       has_bias=bias is not None, relu=relu, act_qt=act_qt,
                       packed=packed, emit_code=out_code, has_xscale=per_row)

@@ -19,7 +19,7 @@ Three layers live here:
 
 Split-row layout
 ----------------
-``pack_rows(codes, bits)`` pads K (the reduction dim) up to ``PACK_ALIGN``,
+``pack_rows(codes, bits)`` pads K (the reduction dim) up to ``pack_align(bits)``,
 splits the rows into ``r = 8 // bits`` contiguous chunks of ``Kp / r`` rows,
 and packs row ``i`` of every chunk into one byte (chunk ``j`` occupies bit
 field ``j*bits``).  A contiguous *byte-row* block of the packed buffer then
@@ -41,12 +41,21 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# K-dim alignment of the packed buffers: matches the qmatmul kernels'
-# _MIN_TILE so a stored packed view is directly streamable (no repack)
+# the qmatmul kernels' lane tile: K of an unpacked (W8) weight is padded to it
 PACK_ALIGN = 128
 
 # working points with a sub-byte packed representation
 SUB_BYTE_BITS = (4, 2)
+
+
+def pack_align(bits: int) -> int:
+    """K alignment of a ``bits``-bit matmul weight: ``PACK_ALIGN`` per
+    packed activation view, i.e. ``128 * (8 // bits)`` below W8.  The kernel
+    streams a (bk/r)-row packed tile against r activation blocks of bk/r
+    lanes each, and the TPU compiler needs every such block to span whole
+    128-lane tiles — so a stored view padded this way streams without a
+    repack."""
+    return PACK_ALIGN * (8 // bits) if bits in SUB_BYTE_BITS else PACK_ALIGN
 
 
 def _crc32(arr) -> int:
@@ -103,10 +112,11 @@ def _pad_rows(codes, align: int):
     return jnp.pad(codes, ((0, r),) + ((0, 0),) * (codes.ndim - 1))
 
 
-def pack_rows(codes, bits: int, align: int = PACK_ALIGN):
+def pack_rows(codes, bits: int, align: Optional[int] = None):
     """int8 master codes (K, N) -> split-row packed uint8 (Kp/r, N).
 
-    ``r = 8 // bits``; K is zero-padded to ``align`` (code 0 packs to a zero
+    ``r = 8 // bits``; K is zero-padded to ``align`` (default
+    :func:`pack_align`; code 0 packs to a zero
     field and contributes nothing to a MAC).  Byte ``i`` holds the ``bits``-bit
     integer ``q`` of rows ``i + j*(Kp/r)`` for ``j = 0..r-1``, field ``j`` at
     bit ``j*bits``.  ``q`` is the rounded nested truncation — identical to
@@ -116,7 +126,7 @@ def pack_rows(codes, bits: int, align: int = PACK_ALIGN):
     shift = 8 - bits
     step = 1 << shift
     half = 1 << (bits - 1)
-    cp = _pad_rows(jnp.asarray(codes), align)
+    cp = _pad_rows(jnp.asarray(codes), align or pack_align(bits))
     kp = cp.shape[0]
     q = jnp.clip(jnp.round(cp.astype(jnp.float32) / step),
                  -half, half - 1).astype(jnp.int32)
@@ -208,22 +218,27 @@ class PackedTensor:
     def scale_1d(self) -> jax.Array:
         return self.scale.reshape(-1)
 
-    def packed_view(self, bits: int, align: int = PACK_ALIGN) -> jax.Array:
+    def packed_view(self, bits: int, align: Optional[int] = None) -> jax.Array:
         """Split-row sub-byte packed W4/W2 buffer (cached; K padded to
         ``align`` so kernels stream it without a repack).  The default
-        alignment matches the qmatmul tile; the depthwise-direct kernels pass
+        alignment is the qmatmul kernels' :func:`pack_align`; the
+        depthwise-direct kernels pass
         a small alignment so a 3x3 window (K = 9) is not padded 14x."""
         if bits not in SUB_BYTE_BITS:
             raise ValueError(f"packed_view is for bits in {SUB_BYTE_BITS}, "
                              f"got {bits} (the W8 view IS the master codes)")
-        key = (bits, int(align))
+        key = (bits, int(align or pack_align(bits)))
         # first-touch derivation is lock-guarded: the fleet heal path builds
         # a fresh replica's executables while sibling pumps serve from the
         # same PackedWeights, so two threads may race the cache miss
         with self._lock:
             buf = self._packed.get(key)
             if buf is None:
-                buf = pack_rows(self.codes_2d(), bits, align=align)
+                # first touch usually happens while a served executable is
+                # traced: derive the buffer eagerly from the concrete master
+                # codes so the cache holds (and the CRC seals) real bytes
+                with jax.ensure_compile_time_eval():
+                    buf = pack_rows(self.codes_2d(), bits, align=key[1])
                 self._packed[key] = buf
                 self._crc[("view", *key)] = _crc32(buf)
         return buf
@@ -281,7 +296,7 @@ class PackedTensor:
                             for r in self.regions(name, bits))
                 if m is not None]
 
-    def repair_view(self, bits: int, align: int = PACK_ALIGN) -> jax.Array:
+    def repair_view(self, bits: int, align: Optional[int] = None) -> jax.Array:
         """Re-derive one packed view bit-exactly from the master codes and
         reseal its checksum — the self-healing half of SDC handling (views
         are nested truncations, so repair costs one re-pack, no reload).
@@ -291,9 +306,9 @@ class PackedTensor:
         if bits not in SUB_BYTE_BITS:
             raise ValueError(f"only sub-byte views are repairable, got "
                              f"bits={bits}")
-        key = (bits, int(align))
+        key = (bits, int(align or pack_align(bits)))
         with self._lock:
-            fresh = pack_rows(self.codes_2d(), bits, align=align)
+            fresh = pack_rows(self.codes_2d(), bits, align=key[1])
             self._packed[key] = fresh
             self._crc[("view", *key)] = _crc32(fresh)
         return fresh
@@ -303,11 +318,13 @@ class PackedTensor:
         """Master storage: 1 byte/code + 4 bytes/scale (shared by all points)."""
         return int(self.codes.size) + 4 * int(self.scale.size)
 
-    def view_nbytes(self, bits: int, align: int = PACK_ALIGN) -> int:
+    def view_nbytes(self, bits: int, align: Optional[int] = None) -> int:
         """Resident HBM bytes of the ``bits``-bit view on the kernel path:
-        the streamed weight buffer (K padded to ``align``, sub-byte packed
-        below W8) plus the f32 channel scales."""
+        the streamed weight buffer (K padded to ``align``, default
+        :func:`pack_align`, sub-byte packed below W8) plus the f32 channel
+        scales."""
         k, n = self.codes_2d().shape
+        align = align or pack_align(bits)
         kp = k + ((-k) % align)
         if bits in SUB_BYTE_BITS:
             buf = (kp // (8 // bits)) * n
@@ -403,6 +420,16 @@ class PackedWeights:
         caps = caps or {}
         return sum(t.view_nbytes(min(bits, caps.get(name, bits)))
                    for name, t in self.tensors.items())
+
+    def view_bytes_bound(self, bits: int) -> int:
+        """Upper bound of :meth:`view_bytes` at ``bits``: ``bits/8`` of the
+        W8 view plus, per tensor, one ``PACK_ALIGN``-row tile and its scales.
+        A packed view pads K to ``pack_align(bits) = 128 * 8/bits`` rows
+        where the W8 view pads it to 128, so a tensor whose K is far below
+        ``128 * 8/bits`` (a 3x3 conv over few channels) saves nothing."""
+        slack = sum((PACK_ALIGN + 4) * int(t.codes_2d().shape[1])
+                    for t in self.tensors.values())
+        return (bits * self.view_bytes(8)) // 8 + slack
 
     def sharing_report(self, n_points: int = 3) -> Dict[str, float]:
         """Merged-vs-separate weight storage for ``n_points`` working points

@@ -37,8 +37,9 @@ def test_sharded_train_step_matches_single_device():
         # single device
         s1, m1 = jax.jit(make_train_step(cfg, opt))(state, batch)
         # sharded 2x4
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((2, 4), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         step = make_train_step(cfg, opt, mesh=mesh, tp_total=4)
         st_sh = state_shardings(cfg, state, mesh)
         b_sh = batch_shardings(batch, mesh)
@@ -100,8 +101,9 @@ def test_moe_shard_map_matches_local():
                             w_up=p1["layers/moe/w_up"][0],
                             w_down=p1["layers/moe/w_down"][0])
         y1, lb1, z1 = moe_block(x, lp, cfg, None, 1)
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((2, 4), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         lp4 = MoELayerParams(router=p1["layers/moe/router"][0],
                              w_gate=to_ep(p1["layers/moe/w_gate"], False)[0],
                              w_up=to_ep(p1["layers/moe/w_up"], False)[0],
@@ -143,11 +145,11 @@ def test_dist_batched_executable_serves_indivisible_batches():
         from repro.core.reader import cnn_to_ir
         from repro.core.passes import PassManager, structural_pipeline
         from repro.core.writers.dist_writer import DistWriter
-        from repro.launch.mesh import compat_make_mesh
+        from jax.sharding import AxisType
         params = cnn.init_params(CNN, jax.random.PRNGKey(0))
         g = cnn_to_ir(CNN, {k: np.asarray(v) for k, v in params.items()})
         g = PassManager(structural_pipeline()).run(g)
-        mesh = compat_make_mesh((4,), ("data",))
+        mesh = jax.make_mesh((4,), ("data",), axis_types=(AxisType.Auto,))
         w = DistWriter(g)
         exe = w.build_batched(mesh)
         ref = w.build()
@@ -181,12 +183,12 @@ def test_accel_server_coalesces_onto_mesh():
         from repro.core.reader import cnn_to_ir
         from repro.core.passes import PassManager, structural_pipeline
         from repro.core.writers.dist_writer import DistWriter
-        from repro.launch.mesh import compat_make_mesh
+        from jax.sharding import AxisType
         from repro.runtime.serve import AccelServer
         params = cnn.init_params(CNN, jax.random.PRNGKey(0))
         g = cnn_to_ir(CNN, {k: np.asarray(v) for k, v in params.items()})
         g = PassManager(structural_pipeline()).run(g)
-        mesh = compat_make_mesh((4,), ("data",))
+        mesh = jax.make_mesh((4,), ("data",), axis_types=(AxisType.Auto,))
         w = DistWriter(g)
         traced = []
         srv = AccelServer(w.build_batched(mesh, on_compile=traced.append),

@@ -15,7 +15,7 @@ import pytest
 
 from repro.dse.pareto import FrontFormatError, ParetoFront, ParetoPoint
 from repro.kernels import autotune
-from repro.quant.pack import PACK_ALIGN, PackedWeights
+from repro.quant.pack import PackedWeights, pack_align
 from repro.runtime.fleet import FleetRouter, HealthState
 from repro.runtime.integrity import (BitFlipInjector, CanarySet,
                                      IntegrityError, Scrubber)
@@ -156,7 +156,7 @@ def test_packed_view_cache_is_thread_safe():
     for th in threads:
         th.join(10.0)
     assert not errs
-    assert set(t._packed) == {(4, PACK_ALIGN), (2, PACK_ALIGN)}
+    assert set(t._packed) == {(4, pack_align(4)), (2, pack_align(2))}
     for bits in (4, 2):
         bufs = [b for bb, b in results if bb == bits]
         assert all(np.array_equal(bufs[0], b) for b in bufs)
