@@ -16,6 +16,9 @@ stream writer stamps on its topology.  ``dtconfig`` accepts either a uniform
 :class:`~repro.quant.qtypes.DatatypeConfig` or a heterogeneous
 :class:`~repro.quant.qtypes.PrecisionMap`; ``explore_mixed_precision``
 searches for the latter greedily against the float reference.
+While a span recorder is on (:mod:`repro.spans`), ``run`` records its
+phases as ``flow.transform``, ``flow.calibrate`` and one ``flow.write`` per
+target.
 """
 from __future__ import annotations
 
@@ -27,6 +30,7 @@ from typing import (Any, Callable, Dict, List, Optional, Sequence, Tuple,
 import jax
 import jax.numpy as jnp
 
+from repro import spans
 from repro.core.ir import Graph
 from repro.core.passes import (PassManager, default_pipeline,
                                explore_mixed_precision, strip_precision,
@@ -231,14 +235,16 @@ class DesignFlow:
             if t not in WRITERS:
                 raise KeyError(f"unknown target {t!r}; have {tuple(WRITERS)}")
         default_dt, min_act, min_wt = _split_precision(dtconfig)
-        g = self.transform(dtconfig, passes)
+        with spans.span("flow.transform"):
+            g = self.transform(dtconfig, passes)
         act_ranges: Dict[str, float] = {}
         if calib_inputs is not None and min_act < 32:
             # calibrate on the *float* view of the compiled graph — with the
             # precision annotations stripped — so recorded ranges are true
             # activation ranges, not values already clipped by quantization
-            act_ranges = self.calibrate(*calib_inputs,
-                                        graph=strip_precision(g))
+            with spans.span("flow.calibrate"):
+                act_ranges = self.calibrate(*calib_inputs,
+                                            graph=strip_precision(g))
         stray = sorted(set(writer_kwargs or {}) - set(targets))
         if stray:
             raise KeyError(f"writer_kwargs for {stray} not in targets "
@@ -262,10 +268,11 @@ class DesignFlow:
                     f"{accepted if accepted else 'no options'}")
         writers, exes, batched = {}, {}, {}
         for t in targets:
-            w = WRITERS[t](g, default_dt, act_ranges, **wkw[t])
-            writers[t] = w
-            exes[t] = w.build()
-            batched[t] = w.build_batched(max_entries=batch_cache)
+            with spans.span("flow.write", target=t):
+                w = WRITERS[t](g, default_dt, act_ranges, **wkw[t])
+                writers[t] = w
+                exes[t] = w.build()
+                batched[t] = w.build_batched(max_entries=batch_cache)
         stats = {}
         if dtconfig is not None and min_wt < 32:
             stats = graph_weight_stats(g, default_dt)

@@ -19,16 +19,23 @@ annotated IR:
 * ``build_batched`` wraps the interpreter in a :class:`BatchedExecutable` —
   a batch-polymorphic artifact that re-jits per concrete input signature
   with an LRU of traced shapes, so one compiled graph (symbolic leading dim,
-  see :data:`repro.core.ir.BATCH`) serves batch 1..N without recompiling.
+  see :data:`repro.core.ir.BATCH`) serves batch 1..N without recompiling;
+* each node's actor and its output quantization run under
+  ``jax.named_scope(node.name)``, so every operation of the compiled program
+  names its IR node in its ``op_name`` metadata, which
+  :class:`~repro.core.writers.scopes.NodeMap` reads back (a device
+  profile's operations can be tied to the graph).
 """
 from __future__ import annotations
 
+import time
 from collections import OrderedDict
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro import spans
 from repro.core.ir import Graph, Node
 from repro.core.writers.registry import OP_REGISTRY, registered_ops, resolve
 from repro.quant.fixedpoint import fake_quant
@@ -51,6 +58,11 @@ class BatchedExecutable:
     *own* ``jax.jit`` object so eviction actually releases the trace — one
     shared jit would grow an unbounded internal shape cache, which is what
     this class exists to bound for long-running serving.
+
+    While a span recorder is on (:mod:`repro.spans`), a call whose
+    signature missed the cache is one ``exe.compile`` span: the trace,
+    lowering, compile (or compile-cache load) and kernel autotuning of that
+    signature, and its first dispatch.
     """
 
     def __init__(self, fn: Callable, max_entries: int = 8,
@@ -95,7 +107,19 @@ class BatchedExecutable:
         return exe
 
     def __call__(self, *inputs):
-        return self.executable_for(*inputs)(*inputs)
+        rec = spans.active()
+        if rec is None:
+            return self.executable_for(*inputs)(*inputs)
+        t0, misses = time.time_ns(), self.misses
+        exe = self.executable_for(*inputs)
+        if self.misses == misses:
+            return exe(*inputs)
+        try:
+            return exe(*inputs)
+        finally:
+            rows = jnp.shape(inputs[0])[:1] if inputs else ()
+            rec.add("exe.compile", t0, time.time_ns(),
+                    bucket=int(rows[0]) if rows else None)
 
     @property
     def cached_signatures(self) -> Tuple[Signature, ...]:
@@ -211,10 +235,11 @@ class JaxWriter:
             for n, x in zip(in_names, inputs):
                 env[n] = self._act_q(n, x)
             for node, impl in impls:
-                y = impl(node, env)
-                outs = y if isinstance(y, tuple) else (y,)
-                for oname, oval in zip(node.outputs, outs):
-                    env[oname] = self._act_q(oname, oval, node)
+                with jax.named_scope(node.name):
+                    y = impl(node, env)
+                    outs = y if isinstance(y, tuple) else (y,)
+                    for oname, oval in zip(node.outputs, outs):
+                        env[oname] = self._act_q(oname, oval, node)
             outs = tuple(self._materialize(env[o]) for o in self.graph.outputs)
             if capture:
                 return outs[0] if len(outs) == 1 else outs, env

@@ -29,7 +29,7 @@ from repro.kernels import autotune
 from repro.kernels.qconv_dw.kernel import DEFAULT_BC, build_dw_call
 from repro.kernels.qconv_dw.ref import (ActQt, normalize_pads, out_spatial,
                                         qconv_dw_int8_act_ref, qconv_dw_ref)
-from repro.kernels.qmatmul.ops import _pad_to, _time_call, resolve_interpret
+from repro.kernels.qmatmul.ops import _fastest, _pad_to, resolve_interpret
 from repro.quant.pack import unpack_rows
 
 # split-row packing alignment for depthwise tap rows: the reduction is kh*kw
@@ -111,15 +111,10 @@ def pick_blocks_dw(B: int, Hp: int, Wpp: int, Cp: int, *, kh: int, kw: int,
         _BC_CACHE[key] = default
         return default
     args = _synth_dw_args(B, Hp, Wpp, Cp, kh, w_rows, int8_act, packed)
-    best, best_t = default, float("inf")
-    for bc in sorted(cands):
-        call = build_dw_call(B, Hp, Wpp, Cp, kh=kh, kw=kw, sh=sh, sw=sw,
-                             oh=oh, ow=ow, w_rows=w_rows, bits=bits,
-                             int8_act=int8_act, bc=bc, interpret=False,
-                             packed=packed)
-        t = _time_call(call, args)
-        if t < best_t:
-            best, best_t = bc, t
+    best = _fastest(cands, lambda bc: build_dw_call(
+        B, Hp, Wpp, Cp, kh=kh, kw=kw, sh=sh, sw=sw, oh=oh, ow=ow,
+        w_rows=w_rows, bits=bits, int8_act=int8_act, bc=bc, interpret=False,
+        packed=packed), args)
     _BC_CACHE[key] = best
     autotune.disk_put(dk, (best,))
     return best

@@ -24,11 +24,12 @@ from __future__ import annotations
 
 import functools
 import time
-from typing import Dict, Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro import spans
 from repro.kernels import autotune
 from repro.kernels.qmatmul.kernel import (ActQt, build_call, DEFAULT_BM,
                                           DEFAULT_BN, DEFAULT_BK)
@@ -107,6 +108,23 @@ def _default_blocks(M: int, K: int, N: int,
             _fit(K, DEFAULT_BK, k_align))
 
 
+def _fastest(cands, make_call: Callable, args):
+    """The candidate whose call ``make_call(c)`` runs ``args`` fastest: one
+    timing sweep, recorded as a ``kernels.autotune`` span and counted in
+    ``kernels.autotune_sweeps`` while a span recorder is on."""
+    rec = spans.active()
+    t0 = 0 if rec is None else time.time_ns()
+    best, best_t = None, float("inf")
+    for c in sorted(cands):
+        t = _time_call(make_call(c), args)
+        if t < best_t:
+            best, best_t = c, t
+    if rec is not None:
+        rec.add("kernels.autotune", t0, time.time_ns(), candidates=len(cands))
+        rec.count("kernels.autotune_sweeps")
+    return best
+
+
 def _time_call(call, args, iters: int = 3) -> float:
     jax.block_until_ready(call(*args))          # compile + warm
     best = float("inf")
@@ -171,13 +189,9 @@ def pick_blocks(M: int, K: int, N: int, bits: int, interpret: bool,
         _BLOCK_CACHE[key] = default
         return default
     args = _synth_args(M, K, N, int8_act, packed, r)
-    best, best_t = default, float("inf")
-    for bm, bn, bk in sorted(cands):
-        call = build_call(M, K, N, bits=bits, int8_act=int8_act,
-                          bm=bm, bn=bn, bk=bk, interpret=False, packed=packed)
-        t = _time_call(call, args)
-        if t < best_t:
-            best, best_t = (bm, bn, bk), t
+    best = _fastest(cands, lambda c: build_call(
+        M, K, N, bits=bits, int8_act=int8_act, bm=c[0], bn=c[1], bk=c[2],
+        interpret=False, packed=packed), args)
     _BLOCK_CACHE[key] = best
     _disk_put(key, best)
     return best

@@ -75,7 +75,9 @@ class Request:
 
     A submission larger than ``max_batch`` is *split*: the queue holds its
     chunk requests and the caller gets back a parent whose ``children`` lists
-    the chunk rids in order — the executor demuxes them back to one ticket."""
+    the chunk rids in order — the executor demuxes them back to one ticket.
+    ``submit_ns`` is the wall-clock submit time a span recorder asked for
+    (``time.time_ns``; 0 when none was recording)."""
 
     rid: int
     inputs: Tuple[Any, ...]
@@ -83,6 +85,7 @@ class Request:
     arrival: float
     budget: float = 1.0
     children: Optional[List[int]] = None
+    submit_ns: int = 0
 
 
 @dataclass
@@ -112,11 +115,14 @@ class LatencyEWMA:
     """Per-bucket execution-latency EWMA — the measurement side of the
     closed bucket-selection loop.
 
-    The executor observes how long each bucket actually takes on the device
-    (:class:`~repro.runtime.serve.BatchReport.exec_s`); the policy consults
-    the estimates when choosing the next bucket.  An exponentially weighted
-    moving average keeps the estimate fresh under drift (retraces, cache
-    evictions, thermal/clock changes) without storing a window per bucket.
+    The executor observes how long each bucket takes from dispatch to forced
+    output on the host's clock
+    (:class:`~repro.runtime.serve.BatchReport.exec_s`: the device's work
+    plus any wait behind earlier pipelined batches, and a first call's
+    compile); the policy consults the estimates when choosing the next
+    bucket.  An exponentially weighted moving average keeps the estimate
+    fresh under drift (retraces, cache evictions, thermal/clock changes)
+    without storing a window per bucket.
     """
 
     def __init__(self, alpha: float = 0.25):
@@ -311,8 +317,10 @@ class CoalescingScheduler:
     def pending_rows(self) -> int:
         return sum(r.size for r in self._queue)
 
-    def submit(self, inputs: Sequence[Any], budget: float = 1.0) -> Request:
-        """Enqueue one request (a tuple of arrays sharing the leading dim)."""
+    def submit(self, inputs: Sequence[Any], budget: float = 1.0,
+               submit_ns: int = 0) -> Request:
+        """Enqueue one request (a tuple of arrays sharing the leading dim);
+        ``submit_ns`` is stamped on it and on its chunks."""
         inputs = tuple(inputs)
         if not inputs:
             raise ValueError("request has no inputs")
@@ -340,7 +348,8 @@ class CoalescingScheduler:
                 f"queue_depth {self.queue_depth} reached; retry after a pump"
             )
         if size <= self.max_batch:
-            req = Request(next(self._rids), inputs, size, self.clock(), budget)
+            req = Request(next(self._rids), inputs, size, self.clock(), budget,
+                          submit_ns=submit_ns)
             self._queue.append(req)
             self.submitted += 1
             return req
@@ -348,11 +357,13 @@ class CoalescingScheduler:
         # back to back, so FIFO packing keeps them contiguous) and hand back
         # a parent the executor demuxes to one ticket
         arrival = self.clock()
-        parent = Request(next(self._rids), inputs, size, arrival, budget, children=[])
+        parent = Request(next(self._rids), inputs, size, arrival, budget,
+                         children=[], submit_ns=submit_ns)
         for off in range(0, size, self.max_batch):
             chunk = tuple(x[off : off + self.max_batch] for x in inputs)
             child = Request(
-                next(self._rids), chunk, int(chunk[0].shape[0]), arrival, budget
+                next(self._rids), chunk, int(chunk[0].shape[0]), arrival, budget,
+                submit_ns=submit_ns,
             )
             self._queue.append(child)
             parent.children.append(child.rid)

@@ -13,6 +13,7 @@ point per scheduled batch.
 """
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from collections import Counter, deque
@@ -25,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import spans
 from repro.configs.base import ModelConfig
 from repro.core.adaptive import (PointSelector, RuntimePolicy,
                                  ServiceObjective, SLOController,
@@ -43,6 +45,8 @@ __all__ = [
     "Ticket", "decode_state_shardings", "greedy_generate", "make_decode_step",
     "make_prefill_step",
 ]
+
+PUMP_THREAD = "accel-server-pump"   # the background pump's thread name
 
 
 class ServerStopped(RuntimeError):
@@ -210,7 +214,8 @@ class _BatchFailure:
 
 @dataclass
 class BatchReport:
-    """Telemetry for one executed batch."""
+    """Telemetry for one executed batch; ``batch`` is the server's id for
+    it, the id its spans carry (:mod:`repro.spans`)."""
     bucket: int          # leading-dim size actually executed (after padding)
     rows: int            # useful rows (sum of member request sizes)
     padding: int         # zero rows appended to reach the bucket
@@ -218,7 +223,11 @@ class BatchReport:
     point: Optional[str]  # precision working point, if a policy is attached
     bits: Optional[int] = None   # weight-bits view the executed artifact used
     tenant: str = "default"      # which resident graph served the batch
-    exec_s: Optional[float] = None  # device execution seconds (feeds LatencyEWMA)
+    # host seconds from dispatch to forced output (feeds LatencyEWMA): the
+    # device's work plus any wait behind earlier pipelined batches, and a
+    # first call's trace and compile
+    exec_s: Optional[float] = None
+    batch: Optional[int] = None
 
 
 class Ticket:
@@ -270,6 +279,7 @@ class _Pending:
     point: Optional[str]
     bits: Optional[int]
     t0: float
+    bid: int
 
 
 class _Tenant:
@@ -424,6 +434,10 @@ class AccelServer:
         self._order: List[str] = []          # WRR ring, registration order
         self._rr_pos = 0
         self._rr_credit = 0
+        self._batch_ids = itertools.count()   # BatchReport.batch, span ids
+        # (recorder, end ns) of the pump's last span: the next one starts
+        # there, so the pump's spans tile its time (_span_start)
+        self._pump_mark: Optional[Tuple[spans.Recorder, int]] = None
         self._lock = threading.RLock()
         self._cond = threading.Condition(self._lock)
         self._thread: Optional[threading.Thread] = None
@@ -531,13 +545,15 @@ class AccelServer:
         depth (admission control — other tenants are unaffected).  A request
         whose leading dim exceeds the tenant's ``max_batch`` is transparently
         split into chunk requests and demuxed back to this one ticket."""
+        rec = spans.active()
+        t0 = 0 if rec is None else time.time_ns()
         with self._cond:
             if self._fatal is not None:
                 raise RuntimeError(
                     "server pump died; no new requests accepted"
                 ) from self._fatal
             ten = self._tenant(tenant)
-            req = ten.scheduler.submit(inputs, budget=budget)
+            req = ten.scheduler.submit(inputs, budget=budget, submit_ns=t0)
             tk = Ticket(self, ten.name, req.rid)
             ten.tickets[req.rid] = tk
             if req.children:
@@ -546,11 +562,15 @@ class AccelServer:
                 for c in req.children:
                     ten.child_parent[c] = req.rid
             self._cond.notify_all()
+        if rec is not None:
+            rec.add("serve.submit", t0, time.time_ns(), rid=req.rid)
         return tk
 
     # -- batch selection (weighted round-robin across tenants) ---------------
-    def _next_batch(self, flush: bool) -> Optional[Tuple[_Tenant, ScheduledBatch]]:
-        """Pop the next due batch under WRR, or None.  Caller holds the lock.
+    def _next_batch(self, flush: bool
+                    ) -> Optional[Tuple[_Tenant, ScheduledBatch, int]]:
+        """Pop the next due batch under WRR with its batch id, or None.
+        Caller holds the lock.
 
         Each tenant may dispatch up to ``weight`` batches per turn while it
         has work ready; an idle or exhausted tenant forfeits the rest of its
@@ -564,7 +584,7 @@ class AccelServer:
                 batch = ten.scheduler.ready(ten.cached(), flush=flush)
                 if batch is not None:
                     self._rr_credit -= 1
-                    return ten, batch
+                    return ten, batch, next(self._batch_ids)
             self._rr_pos = (self._rr_pos + 1) % len(names)
             self._rr_credit = self.tenants[names[self._rr_pos]].weight
         return None
@@ -587,8 +607,15 @@ class AccelServer:
             bits = pt.weight_bits
         return exe, point, bits
 
-    def _dispatch(self, ten: _Tenant, batch: ScheduledBatch) -> _Pending:
+    def _dispatch(self, ten: _Tenant, batch: ScheduledBatch, bid: int,
+                  rec: Optional[spans.Recorder] = None,
+                  t_sel: int = 0) -> _Pending:
+        """Assemble and dispatch one batch.  With a recorder, ``t_sel`` is
+        where the batch's ``serve.select`` span starts."""
         exe, point, bits = self._select(ten, batch)
+        if rec is not None:
+            ids = {"batch": bid, "bucket": batch.bucket, "rows": batch.size}
+            t_asm = self._span_end(rec, "serve.select", t_sel, ids)
         # batch assembly and demux stay on the host: jnp.concatenate /
         # per-slice demux would XLA-compile a fresh kernel per distinct
         # request-shape combination, which dwarfs the accelerator call on a
@@ -603,11 +630,19 @@ class AccelServer:
                 col[off:off + p.shape[0]] = p
                 off += p.shape[0]
             cols.append(col)
+        if rec is not None:
+            t_disp = self._span_end(rec, "serve.assemble", t_asm, ids)
+            for r in batch.requests:
+                if r.submit_ns:
+                    rec.add("serve.queue", r.submit_ns, t_disp, spans.REQUESTS,
+                            rid=r.rid, batch=bid)
         t0 = self.clock()
         out = exe(*cols)
+        if rec is not None:
+            self._span_end(rec, "serve.dispatch", t_disp, ids)
         multi = isinstance(out, tuple)
         return _Pending(ten, batch, tuple(out if multi else (out,)), multi,
-                        point, bits, t0)
+                        point, bits, t0, bid)
 
     @staticmethod
     def _finite(sliced: Tuple[np.ndarray, ...]) -> bool:
@@ -617,10 +652,16 @@ class AccelServer:
                    for o in sliced if np.issubdtype(o.dtype, np.floating))
 
     def _finish(self, pending: _Pending) -> None:
+        rec = spans.active()
+        t_force = 0 if rec is None else self._span_start(rec)
         # forcing to numpy blocks on the device; everything after is host
         outs = tuple(np.asarray(o) for o in pending.outs)
         done = self.clock()
         ten, batch = pending.tenant, pending.batch
+        if rec is not None:
+            ids = {"batch": pending.bid, "bucket": batch.bucket,
+                   "rows": batch.size}
+            t_demux = self._span_end(rec, "serve.force", t_force, ids)
         exec_s = done - pending.t0
         with self._lock:
             off = 0
@@ -649,7 +690,26 @@ class AccelServer:
             ten.executed_batches += 1
             ten.reports.append(BatchReport(
                 batch.bucket, batch.size, batch.padding, len(batch.requests),
-                pending.point, pending.bits, ten.name, exec_s))
+                pending.point, pending.bits, ten.name, exec_s, pending.bid))
+        if rec is not None:
+            self._span_end(rec, "serve.demux", t_demux, ids)
+
+    def _span_start(self, rec: spans.Recorder) -> int:
+        """Where the pump's next span starts: where its last one ended (in
+        this recording), so that its spans tile the pump's time; the loop
+        between two spans, and a wait for the interpreter lock there, count
+        in the span that follows."""
+        mark = self._pump_mark
+        return mark[1] if mark is not None and mark[0] is rec \
+            else time.time_ns()
+
+    def _span_end(self, rec: spans.Recorder, name: str, start: int,
+                  ids: Optional[dict] = None) -> int:
+        """Record one pump span ending now; returns its end."""
+        end = time.time_ns()
+        rec.add(name, start, end, **(ids or {}))
+        self._pump_mark = (rec, end)
+        return end
 
     def _fail_batch(self, ten: _Tenant, batch: ScheduledBatch,
                     err: BaseException) -> None:
@@ -663,11 +723,12 @@ class AccelServer:
                 else:
                     self._resolve(ten, r.rid, _BatchFailure(err))
 
-    def _run_batch(self, ten: _Tenant, batch: ScheduledBatch) -> None:
+    def _run_batch(self, ten: _Tenant, batch: ScheduledBatch, bid: int,
+                   rec: Optional[spans.Recorder], t_sel: int) -> None:
         """Synchronous execute: dispatch + force, re-raising on failure
         (after resolving the member tickets)."""
         try:
-            self._finish(self._dispatch(ten, batch))
+            self._finish(self._dispatch(ten, batch, bid, rec, t_sel))
         except Exception as e:
             self._fail_batch(ten, batch, e)
             raise
@@ -699,14 +760,16 @@ class AccelServer:
             raise RuntimeError(
                 "background pump running: results arrive via result()/"
                 "tickets; stop() the server to drive it synchronously")
+        self._pump_mark = None      # the caller's time is not the pump's
         n = 0
         while True:
+            rec = spans.active()
+            t_sel = 0 if rec is None else self._span_start(rec)
             with self._lock:
                 nxt = self._next_batch(flush)
             if nxt is None:
                 return n
-            ten, batch = nxt
-            self._run_batch(ten, batch)
+            self._run_batch(*nxt, rec, t_sel)
             n += 1
 
     # -- background pump -----------------------------------------------------
@@ -723,8 +786,9 @@ class AccelServer:
             self._stopping = False
             self._drain_on_stop = True
             self._ever_started = True
+            self._pump_mark = None
             self._thread = threading.Thread(
-                target=self._pump_loop, name="accel-server-pump", daemon=True)
+                target=self._pump_loop, name=PUMP_THREAD, daemon=True)
             self._thread.start()
         return self
 
@@ -843,9 +907,15 @@ class AccelServer:
         try:
             while True:
                 with self._cond:
+                    rec = spans.active()
+                    t_nap = 0 if rec is None else self._span_start(rec)
+                    napped = False
                     while (not self._stopping and self._fatal is None
                            and not self._any_queued()):
                         self._cond.wait(timeout=self._poll_s())
+                        napped = True
+                    if napped and rec is not None:
+                        self._span_end(rec, "serve.nap", t_nap)
                     if self._fatal is not None:
                         # a timed-out stop() already resolved every ticket
                         # and marked the server dead: a late-unwedged pump
@@ -860,7 +930,11 @@ class AccelServer:
                     # work is queued but not yet due (max_wait still
                     # running): nap instead of spinning
                     with self._cond:
+                        rec = spans.active()
+                        t_nap = 0 if rec is None else self._span_start(rec)
                         self._cond.wait(timeout=self._poll_s())
+                        if rec is not None:
+                            self._span_end(rec, "serve.nap", t_nap)
         except BaseException as e:   # noqa: BLE001 — the pump must not die silently
             self._die(e)
 
@@ -872,13 +946,15 @@ class AccelServer:
         inflight: Deque[_Pending] = deque()
         executed = 0
         while True:
+            rec = spans.active()
+            t_sel = 0 if rec is None else self._span_start(rec)
             with self._lock:
                 nxt = self._next_batch(flush)
             if nxt is None:
                 break
-            ten, batch = nxt
+            ten, batch, bid = nxt
             try:
-                inflight.append(self._dispatch(ten, batch))
+                inflight.append(self._dispatch(ten, batch, bid, rec, t_sel))
                 executed += 1
             except Exception as e:
                 self._fail_batch(ten, batch, e)
