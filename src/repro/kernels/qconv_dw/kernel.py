@@ -7,7 +7,9 @@ overlapping *input-row views* of the same padded activation array — block
 height 1 makes the element row equal the block index, so the BlockSpec index
 map ``(b*Hp + oh*sh + j, 0, c)`` expresses the sliding window without any
 data duplication (the same multiple-views-of-one-array trick the qmatmul
-kernel uses for its split-row packed activation chunks).
+kernel uses for its split-row packed activation chunks).  The column
+stride needs no gather either: at ``sw > 1`` the wrapper orders each row's
+columns by phase, so every tap is one contiguous slice (:func:`phase_offset`).
 
 Depthwise structure makes the reduction tiny (``kh*kw`` taps per channel) and
 purely channel-parallel, so the MAC loop is a VPU multiply-accumulate over
@@ -38,14 +40,12 @@ from repro.kernels.qmatmul.ref import ActQt, epilogue_code_ref, epilogue_ref
 DEFAULT_BC = 128
 
 
-def _strided_taps(row, dx: int, sw: int, ow: int, bc: int):
-    """Columns ``dx + sw*o`` for ``o in [0, ow)`` of a (1, Wpp, bc) row tile.
-    Expressed as a contiguous slice + reshape (not a strided slice) so Mosaic
-    lowers it on compiled backends."""
-    if sw == 1:
-        return jax.lax.slice_in_dim(row, dx, dx + ow, axis=1)
-    seg = jax.lax.slice_in_dim(row, dx, dx + sw * ow, axis=1)
-    return seg.reshape(1, ow, sw, bc)[:, :, 0, :]
+def phase_offset(dx: int, sw: int, Wpp: int) -> int:
+    """Start of tap ``dx``'s columns in a row whose ``Wpp`` columns are
+    ordered by phase (``ops._prep_spatial``): input column ``dx + sw*o`` sits
+    at ``phase_offset(dx, sw, Wpp) + o``.  At ``sw == 1`` the order is the
+    identity and the offset is ``dx``."""
+    return (dx % sw) * (Wpp // sw) + dx // sw
 
 
 def qconv_dw_kernel(*refs, kh: int, kw: int, sw: int, ow: int, bits: int,
@@ -57,7 +57,8 @@ def qconv_dw_kernel(*refs, kh: int, kw: int, sw: int, ow: int, bits: int,
 
     ``row_0 .. row_{kh-1}`` — the kh input rows of this output row's window:
     (1, Wpp, bc) views of the SAME padded activation array (int8 codes on the
-    integer path, f32 on the float path);
+    integer path, f32 on the float path), columns ordered by phase at
+    ``sw > 1`` so each tap is a unit-stride slice (:func:`phase_offset`);
     ``w``  — weight taps: int8 codes (KRp, bc) or split-row sub-byte packed
     uint8 (Kp2/r, bc); rows beyond ``kh*kw`` are alignment padding;
     ``s``  — per-channel scale (1, bc), activation scale and sub-byte step
@@ -92,7 +93,8 @@ def qconv_dw_kernel(*refs, kh: int, kw: int, sw: int, ow: int, bits: int,
     for j in range(kh):
         row = rows[j][...].astype(acc_dtype)            # (1, Wpp, bc)
         for dx in range(kw):
-            taps = _strided_taps(row, dx, sw, ow, bc)
+            start = phase_offset(dx, sw, row.shape[1])
+            taps = jax.lax.slice_in_dim(row, start, start + ow, axis=1)
             acc = acc + taps * wmat[j * kw + dx][None, None, :]
 
     y = acc.astype(jnp.float32) * s_ref[...][:, None, :].astype(jnp.float32)
@@ -113,8 +115,9 @@ def build_dw_call(B: int, Hp: int, Wpp: int, Cp: int, *, kh: int, kw: int,
                   emit_code: bool = False):
     """A ``pallas_call`` over a padded depthwise problem.
 
-    Operands: activations reshaped to (B*Hp, Wpp, Cp) with
-    ``Wpp >= (kw-1) + sw*ow`` (so every strided tap slice is in bounds),
+    Operands: activations reshaped to (B*Hp, Wpp, Cp), each row's columns
+    ordered by phase when ``sw > 1``, with ``Wpp`` a multiple of ``sw`` and
+    ``(kw-1)//sw + ow <= Wpp//sw`` (so every tap slice stays in its phase),
     weights (w_rows, Cp) — codes padded to ``w_rows >= kh*kw`` rows, or the
     packed buffer with ``w_rows = Kp2 / (8//bits)`` byte rows — scale (1, Cp)
     and optional bias (1, Cp).  Output: (B*oh, ow, Cp)."""
@@ -125,7 +128,8 @@ def build_dw_call(B: int, Hp: int, Wpp: int, Cp: int, *, kh: int, kw: int,
     if packed:
         assert bits in (4, 2), f"sub-byte packing needs bits in (4, 2): {bits}"
     assert Cp % bc == 0, (Cp, bc)
-    assert Wpp >= (kw - 1) + sw * ow, (Wpp, kw, sw, ow)
+    assert Wpp % sw == 0 and (kw - 1) // sw + ow <= Wpp // sw, \
+        (Wpp, kw, sw, ow)
     grid = (B, oh, Cp // bc)
 
     kern = functools.partial(

@@ -9,13 +9,15 @@ split-row sub-byte W4/W2 weight buffer (:func:`repro.quant.pack.pack_rows`
 at ``align=DW_PACK_ALIGN`` — a 3x3 window packs its 9 tap rows into 16, not
 the matmul tile's 128) unpacked in-VMEM.
 
-Host-side prep pads the spatial window so every strided tap slice stays in
-bounds and the W lane dim tiles cleanly, then hands the kernel ``kh``
-row-shifted *views* of one padded activation array — the patch tensor of the
-legacy im2col + qgemm lowering is never materialized.  Autotuning picks the
-channel block the same way qmatmul picks (bm, bn, bk): in-process L1 dict,
-then the shared versioned disk cache (``repro.kernels.autotune``) under
-``"dw:"``-prefixed keys, then a timing sweep on compiled backends.
+Host-side prep pads the spatial window so every tap slice stays in bounds
+and the W lane dim tiles cleanly, orders each row's columns by phase at a
+column stride > 1 (so every tap is a unit-stride slice), then hands the
+kernel ``kh`` row-shifted *views* of one padded activation array — the
+patch tensor of the legacy im2col + qgemm lowering is never materialized.
+Autotuning picks the channel block the same way qmatmul picks (bm, bn, bk):
+in-process L1 dict, then the shared versioned disk cache
+(``repro.kernels.autotune``) under ``"dw:"``-prefixed keys, then a timing
+sweep on compiled backends.
 """
 from __future__ import annotations
 
@@ -25,6 +27,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import spans
 from repro.kernels import autotune
 from repro.kernels.qconv_dw.kernel import DEFAULT_BC, build_dw_call
 from repro.kernels.qconv_dw.ref import (ActQt, normalize_pads, out_spatial,
@@ -120,15 +123,42 @@ def pick_blocks_dw(B: int, Hp: int, Wpp: int, Cp: int, *, kh: int, kw: int,
     return best
 
 
-def _prep_spatial(xp, kw: int, sw: int, ow: int):
-    """Pad a spatially-padded (B, Hp, Wp, C) activation so the kernel's tap
-    slices and lane tiling line up; returns (x2, Hp, Wpp, Cp, owp) with x2
-    reshaped to the (B*Hp, Wpp, Cp) row-view layout."""
-    B, Hp, Wp, C = xp.shape
+def _prep_spatial(x, hpad, wpad, kw: int, sw: int, ow: int):
+    """Pad a (B, H, W, C) activation by the window's ``hpad``/``wpad`` and on
+    to the kernel's tiling; returns (x2, Hp, Wpp, Cp, owp) with x2 in the
+    (B*Hp, Wpp, Cp) row-view layout.
+
+    At ``sw > 1`` each padded row's columns are also ordered by phase:
+    column ``c`` moves to ``(c % sw) * (Wpp // sw) + c // sw``, so the
+    columns ``dx + sw*o`` of tap ``dx`` are one contiguous slice
+    (``kernel.phase_offset``).  ``Wpp`` is then a multiple of ``8 * sw``, so
+    every phase starts on a sublane tile.  Each phase is a strided slice of
+    ``x``, padded on its own: on the TPU, XLA makes that ``sw`` slices and one
+    pad fusion, where a reshape + transpose of the padded array costs a
+    whole extra copy of it."""
+    B, H, W, C = x.shape
+    Hp, Wp = H + sum(hpad), W + sum(wpad)
     owp = _round_up(ow, 8)
-    wpp = _round_up(max(Wp, (kw - 1) + sw * owp), 8)
+    wpp = _round_up(max(Wp, (kw - 1) + sw * owp), 8 * sw)
     cp = _round_up(C, _LANE)
-    xp = jnp.pad(xp, ((0, 0), (0, 0), (0, wpp - Wp), (0, cp - C)))
+    if sw == 1:
+        xp = jnp.pad(x, ((0, 0), hpad, (wpad[0], wpp - W - wpad[0]),
+                         (0, cp - C)))
+        return xp.reshape(B * Hp, wpp, cp), Hp, wpp, cp, owp
+    rec = spans.active()
+    if rec is not None:
+        rec.count("kernels.dw_phase_views")
+    wq, left = wpp // sw, wpad[0]
+    phases = []
+    for p in range(sw):
+        k0 = max(0, -(-(left - p) // sw))       # first phase column inside x
+        c0 = p + sw * k0 - left                 # ... and its column of x
+        n = len(range(c0, W, sw))
+        cols = jax.lax.slice(x, (0, 0, min(c0, W), 0), (B, H, W, C),
+                             (1, 1, sw, 1))
+        phases.append(jnp.pad(cols, ((0, 0), hpad, (k0, wq - k0 - n),
+                                     (0, cp - C))))
+    xp = jnp.concatenate(phases, axis=2)
     return xp.reshape(B * Hp, wpp, cp), Hp, wpp, cp, owp
 
 
@@ -186,8 +216,8 @@ def qconv_dw(x, codes, scale, bias=None, *, kh: int, kw: int,
     # f32 in the window MACs (not bf16): fixed-point activations make every
     # tap product exact, leaving only epilogue fma-contraction ulps vs the
     # oracle (qmatmul's float path loses bf16 mantissa bits in the MXU)
-    xp = jnp.pad(x.astype(jnp.float32), ((0, 0), hpad, wpad, (0, 0)))
-    x2, Hp, wpp, cp, owp = _prep_spatial(xp, kw, sw, ow)
+    x2, Hp, wpp, cp, owp = _prep_spatial(x.astype(jnp.float32), hpad, wpad,
+                                         kw, sw, ow)
     w, sp, bp, w_rows = _prep_weights(codes, scale, bias, k2, cp, bits, packed)
     if bc is None:
         bc = pick_blocks_dw(B, Hp, wpp, cp, kh=kh, kw=kw, sh=sh, sw=sw,
@@ -236,8 +266,7 @@ def qconv_dw_int8_act(x_codes, x_scale, codes, scale, bias=None, *, kh: int,
                                      out_code=out_code, out_dtype=out_dtype)
     sh, sw = strides
     oh, ow, hpad, wpad = out_spatial(H, W, kh, kw, strides, pads)
-    xp = jnp.pad(x_codes, ((0, 0), hpad, wpad, (0, 0)))
-    x2, Hp, wpp, cp, owp = _prep_spatial(xp, kw, sw, ow)
+    x2, Hp, wpp, cp, owp = _prep_spatial(x_codes, hpad, wpad, kw, sw, ow)
     w, sp, bp, w_rows = _prep_weights(codes, scale, bias, k2, cp, bits, packed)
     # scalar activation scale folds into the channel scale — a power of two,
     # so the fold is bit-exact vs the oracle's grouping

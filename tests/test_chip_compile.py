@@ -10,6 +10,7 @@ program holds the Pallas kernel.  Nothing runs.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -93,18 +94,51 @@ def test_qmatmul_int8_act_emit_code_compiles(one_chip, mkn, bits, packed):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("channels,stride,bits,packed", [
-    (8, 1, 8, False), (16, 2, 4, True)], ids=["dw0-s1-w8", "dw1-s2-w4"])
-def test_qconv_dw_int8_act_compiles(one_chip, channels, stride, bits, packed):
-    """separable-cnn's depthwise stages on its 14x14 maps after the stem."""
+# MobileNetV1-0.25's dw1 at an 8-image bucket: 112x112x16, stride 2
+MBV1_DW1 = (8, 112, 16)
+
+
+def _dw_fn(stride, bits, packed):
+    return functools.partial(qconv_dw_int8_act, kh=3, kw=3,
+                             strides=(stride, stride), bits=bits, relu=True,
+                             act_qt=(4, -128, 127), out_code=True,
+                             packed=packed, interpret=False, use_kernel=True,
+                             bc=128)
+
+
+def _dw_shapes(batch, hw, channels, bits, packed):
     rows = -(-9 // DW_PACK_ALIGN) * DW_PACK_ALIGN // (8 // bits) if packed \
         else 9
-    fn = functools.partial(qconv_dw_int8_act, kh=3, kw=3,
-                           strides=(stride, stride), bits=bits, relu=True,
-                           act_qt=(4, -128, 127), out_code=True, packed=packed,
-                           interpret=False, use_kernel=True, bc=128)
-    text = _compiled_text(
-        fn, one_chip, ((8, 14, 14, channels), jnp.int8), ((), jnp.float32),
-        ((rows, channels), jnp.uint8 if packed else jnp.int8),
-        ((channels,), jnp.float32), ((channels,), jnp.float32))
+    return (((batch, hw, hw, channels), jnp.int8), ((), jnp.float32),
+            ((rows, channels), jnp.uint8 if packed else jnp.int8),
+            ((channels,), jnp.float32), ((channels,), jnp.float32))
+
+
+@pytest.mark.parametrize("batch,hw,channels,stride,bits,packed", [
+    (8, 14, 8, 1, 8, False), (8, 14, 16, 2, 4, True),
+    (*MBV1_DW1, 2, 8, False)], ids=["dw0-s1-w8", "dw1-s2-w4", "mbv1-dw1-s2-w8"])
+def test_qconv_dw_int8_act_compiles(one_chip, batch, hw, channels, stride,
+                                    bits, packed):
+    """separable-cnn's depthwise stages on its 14x14 maps after the stem,
+    and MobileNetV1-0.25's first stride-2 stage at its width."""
+    text = _compiled_text(_dw_fn(stride, bits, packed), one_chip,
+                          *_dw_shapes(batch, hw, channels, bits, packed))
     assert "tpu_custom_call" in text
+
+
+def test_qconv_dw_stride2_row_views_of_one_array(one_chip):
+    """The stride-2 call still hands the kernel its three window rows as
+    views of ONE padded activation array, (B*Hp, Wpp, Cp) with the columns
+    in phase order (Wpp a multiple of 16): the benchmark's operation count
+    takes the leading identical operands as the window height."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in _dw_shapes(*MBV1_DW1, 8, False)]
+    text = jax.jit(_dw_fn(2, 8, False)).lower(*args).as_text()
+    (call,) = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    operands = re.search(r"custom_call @tpu_custom_call\(([^)]*)\)",
+                         call).group(1).split(", ")
+    types = re.search(r": \(([^)]*)\) ->", call).group(1).split(", ")
+    # SAME at stride 2 pads 112 to 113; 2*(56 + 1) columns round to 128
+    assert types[:3] == ["tensor<904x128x128xi8>"] * 3
+    assert len(set(operands[:3])) == 1
+    assert types[3] != types[0]

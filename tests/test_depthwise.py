@@ -10,17 +10,22 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import spans
+from repro.configs.mnist_cnn import CONFIG as MNIST
 from repro.configs.separable_cnn import CONFIG as SEP
+from repro.configs.separable_cnn import SeparableCNNConfig
 from repro.core.flow import DesignFlow
 from repro.core.ir import BATCH, Graph, Node, TensorInfo
 from repro.core.passes import PassManager, structural_pipeline
 from repro.core.passes.fusion import reorder_relu_maxpool
 from repro.core.passes.shape_infer import infer_shapes
-from repro.core.reader import normalize_groups, separable_cnn_to_ir
+from repro.core.reader import (cnn_to_ir, normalize_groups,
+                               separable_cnn_to_ir)
 from repro.core.writers.jax_writer import JaxWriter
 from repro.core.writers.qjax_writer import QJaxWriter
 from repro.kernels import autotune
 from repro.kernels.qconv_dw import ops as dwops
+from repro.kernels.qconv_dw.kernel import phase_offset
 from repro.kernels.qconv_dw.ops import (DW_PACK_ALIGN, pick_blocks_dw,
                                         qconv_dw, qconv_dw_int8_act)
 from repro.kernels.qconv_dw.ref import (expand_dw_codes, out_spatial,
@@ -52,9 +57,12 @@ def _sep_graph(seed=0):
 # kernel vs ref: the integer code domain is bit-exact
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("bits,packed", [
-    (8, False), (4, False), (2, False), (4, True), (2, True)])
-def test_dw_int8_act_kernel_bitexact_vs_ref(bits, packed):
+@pytest.mark.parametrize("bits,packed,stride", [
+    (8, False, 1), (4, False, 1), (2, False, 1), (4, True, 1), (2, True, 1),
+    (8, False, 2), (4, True, 2), (2, True, 2)],
+    ids=["8-False", "4-False", "2-False", "4-True", "2-True",
+         "8-False-s2", "4-True-s2", "2-True-s2"])
+def test_dw_int8_act_kernel_bitexact_vs_ref(bits, packed, stride):
     """Forced interpret-mode direct kernel vs the integer oracle: identical
     int32 window MACs + pow2 scale folds, compared on the output code grid.
 
@@ -67,7 +75,7 @@ def test_dw_int8_act_kernel_bitexact_vs_ref(bits, packed):
     x_codes, xs, codes, scale, bias = _dw_problem(bits)
     w_arg = pack_rows(codes, bits, align=DW_PACK_ALIGN) if packed else codes
     frac = 10
-    kw = dict(kh=3, kw=3, strides=(1, 1), pads="SAME", bits=bits,
+    kw = dict(kh=3, kw=3, strides=(stride, stride), pads="SAME", bits=bits,
               relu=True, act_qt=(frac, -(2 ** 15), 2 ** 15 - 1))
     y_k = qconv_dw_int8_act(x_codes, xs, w_arg, scale, bias, packed=packed,
                             interpret=True, use_kernel=True, **kw)
@@ -79,19 +87,73 @@ def test_dw_int8_act_kernel_bitexact_vs_ref(bits, packed):
     assert np.mean(c_k == c_r) >= 0.99
 
 
-@pytest.mark.parametrize("strides,pads", [
-    ((1, 1), "VALID"), ((2, 2), "SAME"), ((2, 2), "VALID"), ((1, 2), "SAME")])
-def test_dw_int8_act_strides_and_pads_bitexact(strides, pads):
+@pytest.mark.parametrize("strides,pads,hwc", [
+    ((1, 1), "VALID", (11, 10, 8)), ((2, 2), "SAME", (11, 10, 8)),
+    ((2, 2), "VALID", (11, 10, 8)), ((1, 2), "SAME", (11, 10, 8)),
+    # column phases: odd and even widths, each stride, and MobileNet's
+    # 16-channel stride-2 stage on an odd map
+    ((2, 2), "SAME", (9, 13, 8)), ((2, 2), "VALID", (12, 12, 8)),
+    ((1, 2), "SAME", (8, 12, 8)), ((1, 2), "VALID", (10, 11, 8)),
+    ((2, 2), "SAME", (15, 15, 16))],
+    ids=["strides0-VALID", "strides1-SAME", "strides2-VALID", "strides3-SAME",
+         "s22-SAME-odd", "s22-VALID-even", "s12-SAME-even", "s12-VALID-odd",
+         "s22-SAME-15x15x16"])
+def test_dw_int8_act_strides_and_pads_bitexact(strides, pads, hwc):
     # no bias: the jitted kernel may fma-contract acc*s + bias while the
     # eager oracle rounds twice — this test isolates the spatial indexing
-    x_codes, xs, codes, scale, _ = _dw_problem(7, H=11, W=10)
+    H, W, C = hwc
+    x_codes, xs, codes, scale, _ = _dw_problem(7, H=H, W=W, C=C)
     kw = dict(kh=3, kw=3, strides=strides, pads=pads, bits=8)
     y_k = qconv_dw_int8_act(x_codes, xs, codes, scale, None,
                             interpret=True, use_kernel=True, **kw)
     y_r = qconv_dw_int8_act_ref(x_codes, xs, codes, scale, None, **kw)
     assert y_k.shape == y_r.shape == (
-        2, *out_spatial(11, 10, 3, 3, strides, pads)[:2], 8)
+        2, *out_spatial(H, W, 3, 3, strides, pads)[:2], C)
     np.testing.assert_array_equal(np.asarray(y_k), np.asarray(y_r))
+
+
+@pytest.mark.parametrize("sw,W,wpad", [
+    (1, 10, (1, 1)), (2, 11, (1, 1)), (2, 12, (0, 1)), (2, 13, (0, 0)),
+    (3, 14, (1, 1))])
+def test_dw_phase_layout_tap_reads_strided_column(sw, W, wpad):
+    """After ``_prep_spatial``'s column-phase order, the kernel's contiguous
+    slice for tap ``dx`` holds the padded row's columns ``dx + sw*o``."""
+    kw = 3
+    ow = (W + sum(wpad) - kw) // sw + 1
+    # each element holds its column of x, plus one: 0 marks padding
+    x = jnp.broadcast_to(jnp.arange(1, W + 1, dtype=jnp.int8)[None, None, :,
+                                                              None],
+                         (1, 2, W, 3))
+    x2, Hp, wpp, cp, owp = dwops._prep_spatial(x, (0, 0), wpad, kw, sw, ow)
+    assert (Hp, cp) == (2, 128) and wpp % (8 * sw) == 0
+    padded = np.concatenate([np.zeros(wpad[0]), np.arange(1, W + 1),
+                             np.zeros(wpp)])
+    row = np.asarray(x2)[1, :, 0]
+    for dx in range(kw):
+        start = phase_offset(dx, sw, wpp)
+        taps = row[start:start + owp]
+        np.testing.assert_array_equal(taps, padded[dx + sw * np.arange(owp)])
+    assert not np.asarray(x2)[:, :, 3:].any()          # channel padding
+
+
+def test_dw_phase_views_counted_per_strided_build():
+    """``kernels.dw_phase_views`` counts each depthwise call built with a
+    column stride > 1 while a recorder is on, and nothing at stride 1."""
+    x_codes, xs, codes, scale, _ = _dw_problem(13, B=1, H=7, W=13, C=24)
+    kw = dict(kh=3, kw=3, bits=8, interpret=True, use_kernel=True)
+    rec = spans.enable()
+    try:
+        qconv_dw_int8_act(x_codes, xs, codes, scale, None, strides=(1, 1),
+                          **kw)
+        assert rec.counters.get("kernels.dw_phase_views", 0) == 0
+        qconv_dw_int8_act(x_codes, xs, codes, scale, None, strides=(2, 2),
+                          **kw)
+        assert rec.counters["kernels.dw_phase_views"] == 1
+        qconv_dw(x_codes.astype(jnp.float32), codes, scale, None,
+                 strides=(1, 2), **kw)
+        assert rec.counters["kernels.dw_phase_views"] == 2
+    finally:
+        spans.disable()
 
 
 @pytest.mark.parametrize("bits", [8, 4, 2])
@@ -120,14 +182,15 @@ def test_dw_fallback_path_is_the_ref():
     np.testing.assert_array_equal(np.asarray(y_f), np.asarray(y_r))
 
 
-@pytest.mark.parametrize("bits", [8, 4])
-def test_dw_float_kernel_matches_ref_to_ulp(bits):
+@pytest.mark.parametrize("bits,stride", [(8, 1), (4, 1), (8, 2), (4, 2)],
+                         ids=["8", "4", "8-s2", "4-s2"])
+def test_dw_float_kernel_matches_ref_to_ulp(bits, stride):
     """Float-activation path: identical window products (f32, fixed-point
     exact), but XLA may fma-contract the scale/bias epilogue — ulp-of-max
     tolerance, the same contract qmatmul's float path carries."""
     x = jax.random.uniform(jax.random.PRNGKey(11), (2, 9, 9, 8), jnp.float32)
     _, _, codes, scale, bias = _dw_problem(11)
-    kw = dict(kh=3, kw=3, bits=bits, relu=True)
+    kw = dict(kh=3, kw=3, strides=(stride, stride), bits=bits, relu=True)
     y_k = qconv_dw(x, codes, scale, bias, interpret=True, use_kernel=True,
                    **kw)
     y_r = qconv_dw_ref(x, codes, scale, bias, **kw)
@@ -385,6 +448,43 @@ def test_writer_direct_kernel_vs_im2col_bitexact_forced_interpret():
     y_dir = np.asarray(_d8_flow(g, calib, "direct", **kw).batched["qjax"](x))
     y_im = np.asarray(_d8_flow(g, calib, "im2col", **kw).batched["qjax"](x))
     np.testing.assert_array_equal(y_dir, y_im)
+
+
+def _seeded_params(init, cfg):
+    """Parameters of ``init``'s shapes from numpy (no per-shape compile)."""
+    shapes = jax.eval_shape(lambda: init(cfg, jax.random.PRNGKey(0)))
+    rng = np.random.default_rng(0)
+    return {k: (rng.standard_normal(v.shape) * 0.1
+                + k.endswith(("/scale", "/var"))).astype(np.float32)
+            for k, v in shapes.items()}
+
+
+def test_dw_phase_views_per_bucket_program():
+    """A MobileNetV1-0.25-shaped program (its 13 blocks, four of them
+    stride 2, on a 32x32 map) builds four phase-ordered depthwise calls per
+    bucket; mnist-cnn, with no depthwise node, builds none."""
+    mbv1 = SeparableCNNConfig(
+        name="mbv1-0.25-32", image_hw=(32, 32), in_channels=3,
+        blocks=((16, 1), (32, 2), (32, 1), (64, 2), (64, 1), (128, 2),
+                (128, 1), (128, 1), (128, 1), (128, 1), (128, 1), (256, 2),
+                (256, 1)))
+    cases = ((separable_cnn_to_ir(mbv1, _seeded_params(
+                  cnn.init_separable_params, mbv1)), (32, 32, 3), 4),
+             (cnn_to_ir(MNIST, _seeded_params(cnn.init_params, MNIST)),
+              (28, 28, 1), 0))
+    for g, hwc, per_bucket in cases:
+        w = QJaxWriter(g, DatatypeConfig(8, 8), use_kernel=True,
+                       interpret=True)
+        rec = spans.enable()
+        try:
+            for n, bucket in enumerate((8, 4), start=1):
+                jax.eval_shape(w.build(bits=8),
+                               jax.ShapeDtypeStruct((bucket, *hwc),
+                                                    jnp.float32))
+                assert rec.counters.get("kernels.dw_phase_views", 0) == \
+                    n * per_bucket
+        finally:
+            spans.disable()
 
 
 def test_writer_validates_dw_mode():
