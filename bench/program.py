@@ -3,7 +3,11 @@ through the program's reader, ``DesignFlow`` to the packed-weight ``qjax``
 target at the configuration's precision, calibrated on seeded rows, and
 served by ``FlowResult.serve_adaptive`` pinned to one working point.
 
-This is the only module of the benchmark that imports the program.
+This module builds and serves, and hands :mod:`run` the program's span
+recorder (``spans``).  The only other code of the benchmark that touches
+the program is each model module's ``to_ir`` (``bench/models/``), which
+imports the program's reader inside the function: the configuration's IR
+graph.
 """
 from __future__ import annotations
 
@@ -17,11 +21,9 @@ ROOT = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT / "src"))
 
 from repro.caches import enable_compile_cache  # noqa: E402,F401 (for run.py)
-from repro.configs.mnist_cnn import CNNConfig  # noqa: E402
-from repro.configs.separable_cnn import SeparableCNNConfig  # noqa: E402
+from repro import spans  # noqa: E402,F401 (for run.py)
 from repro.core.adaptive import FixedSelector, WorkingPoint  # noqa: E402
 from repro.core.flow import DesignFlow  # noqa: E402
-from repro.core.reader import cnn_to_ir, separable_cnn_to_ir  # noqa: E402
 from repro.quant.qtypes import DatatypeConfig  # noqa: E402
 
 import reference  # noqa: E402
@@ -29,20 +31,6 @@ import reference  # noqa: E402
 # the working points the program serves, by name
 POINTS = {"w8": WorkingPoint("w8", 8), "w4": WorkingPoint("w4", 4),
           "w2": WorkingPoint("w2", 2)}
-
-
-def program_config(cfg: dict):
-    """The program's own config object for a benchmark configuration."""
-    common = dict(name=cfg["name"], image_hw=tuple(cfg["image_hw"]),
-                  in_channels=cfg["in_channels"],
-                  kernel_size=cfg["kernel_size"], pool=cfg["pool"],
-                  n_classes=cfg["n_classes"])
-    if cfg["family"] == "cnn":
-        return CNNConfig(conv_channels=tuple(cfg["conv_channels"]), **common), \
-            cnn_to_ir
-    return SeparableCNNConfig(stem_channels=cfg["stem_channels"],
-                              blocks=tuple(tuple(b) for b in cfg["blocks"]),
-                              **common), separable_cnn_to_ir
 
 
 def seed32(seed: int, stream: int) -> int:
@@ -72,8 +60,8 @@ def build(cfg: dict, params, calib, **writer_options):
     (PERF.md).  So the flow is built under JAX's ``highest`` matmul
     precision, the float32 the configuration states.  The served program
     is integer; it is traced at its first call, after this returns."""
-    pcfg, to_ir = program_config(cfg)
-    graph = to_ir(pcfg, {k: np.asarray(v) for k, v in params.items()})
+    graph = reference.model(cfg).to_ir(
+        cfg, {k: np.asarray(v) for k, v in params.items()})
     with jax.default_matmul_precision(cfg["calibration_precision"]):
         return DesignFlow(graph).run(
             targets=("qjax",),

@@ -1,25 +1,27 @@
-"""The plain float32 reference of every benchmark configuration, and the
-seeded weights both it and the served program start from.
+"""The plain float32 references of the benchmark's configurations, the seeded
+weights both they and the served program start from, and the library the
+model modules share.
 
-Nothing here imports the program: the weights are made by the benchmark (one
-jitted call from the seed, on the device) and handed to the program's reader
-and to :func:`forward` alike.  Parameter names follow the program's readers
+A configuration names its model module, ``bench/models/<name>.py``
+(:func:`model`), which holds what is particular to that kind of model:
+``init_weights(cfg, key)``, ``forward(cfg, params, x, act)``,
+``quantized_weights(cfg, params)``, ``quantized_forward(cfg, qw, x,
+steps)``, ``useful_ops_per_image(cfg)`` and ``to_ir(cfg, params)``.  The
+entry points here (:func:`init_weights`, :func:`forward`,
+:func:`quantized_weights`, :func:`quantized_forward`, :func:`act_ranges`)
+hand each configuration to its model module.
+
+Nothing here, and nothing on a model module's reference side, imports the
+program: the weights are made by the benchmark (one jitted call from the
+seed, on the device) and handed to the program's reader (a model module's
+``to_ir``, the one function that imports the program) and to
+:func:`forward` alike.  Parameter names follow the program's readers
 (``conv0/w``, ``stem/w``, ``dw0/w``, ...), which is the only thing the two
 share.
-
-Two families, chosen by a configuration file's ``family`` key:
-
-* ``cnn`` — conv(3x3, SAME) -> batch-norm -> ReLU -> maxpool per block, then
-  flatten -> FC (the paper's Table II model);
-* ``separable`` — conv stem(3x3, SAME) -> ReLU -> maxpool, then per block a
-  depthwise conv(3x3, SAME, stride s) -> BN -> ReLU and a pointwise conv
-  (1x1) -> BN -> ReLU, then flatten -> FC (MobileNetV1's body).
 
 Batch-norm is the inference form with the stored statistics, which the
 seeded weights set to identity (scale 1, bias 0, mean 0, var 1), folded into
 its conv; every bias is zero, as a freshly initialised network has them.
-With a positive batch-norm scale, max-pool commutes with batch-norm and ReLU,
-so the ``cnn`` family pools after them.
 
 :func:`quantized_forward` is the same model at the configuration's stated
 precision (``act_bits``/``weight_bits``), computed in integer codes: what
@@ -28,26 +30,54 @@ the served program should compute, code for code, not merely approximate.
 from __future__ import annotations
 
 import math
+from types import ModuleType
 from typing import Dict
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+import hooks
 
-def fc_in(cfg: dict) -> int:
-    """Input width of the final FC layer (flattened last feature map)."""
-    h, w = cfg["image_hw"]
-    p = cfg["pool"]
-    if cfg["family"] == "cnn":
-        for _ in cfg["conv_channels"]:
-            h, w = h // p, w // p
-        return h * w * cfg["conv_channels"][-1]
-    h, w = h // p, w // p
-    for _, s in cfg["blocks"]:
-        h, w = -(-h // s), -(-w // s)
-    return h * w * cfg["blocks"][-1][0]
 
+def model(cfg: dict) -> ModuleType:
+    """The module of ``cfg``'s model family, ``bench/models/<family>.py``."""
+    mod = hooks.module("models", cfg["family"])
+    if mod is None:
+        raise KeyError(f"no model module bench/models/{cfg['family']}.py "
+                       f"for configuration {cfg['name']!r}")
+    return mod
+
+
+def init_weights(cfg: dict, key) -> Dict[str, jax.Array]:
+    """Seeded He-style weights for ``cfg`` (fan-in scaled normals)."""
+    return model(cfg).init_weights(cfg, key)
+
+
+def forward(cfg: dict, params: Dict[str, jax.Array], x) -> jax.Array:
+    """Float32 logits (B, n_classes) of images ``x`` (B, H, W, C).  Call it
+    under ``jax.default_matmul_precision("highest")``: on a TPU a float32
+    matmul or conv otherwise runs in bfloat16 passes."""
+    return model(cfg).forward(cfg, params, x, _same)
+
+
+def quantized_weights(cfg: dict, params) -> Dict[str, tuple]:
+    """Per layer, ``(codes, scale, bias)`` at ``weight_bits``
+    (:func:`weight_codes`)."""
+    return model(cfg).quantized_weights(cfg, params)
+
+
+def quantized_forward(cfg: dict, qw: Dict[str, tuple], x, steps):
+    """Logits of the model at the configuration's precision, in the integer
+    code domain (:class:`Codes`): the input and every layer output are
+    ``act_bits`` codes on the power-of-two grids ``steps``
+    (:func:`grid_steps`).  ``qw`` is :func:`quantized_weights`.  Call it
+    under HIGHEST matmul precision: then every float32 sum of codes is
+    exact."""
+    return model(cfg).quantized_forward(cfg, qw, x, steps)
+
+
+# -- the float32 model's pieces ------------------------------------------------
 
 def _bn(params, layer: str, c: int) -> None:
     params[f"{layer}/scale"] = jnp.ones((c,), jnp.float32)
@@ -58,40 +88,6 @@ def _bn(params, layer: str, c: int) -> None:
 
 def _normal(key, shape, fan: int):
     return jax.random.normal(key, shape, jnp.float32) / jnp.sqrt(float(fan))
-
-
-def init_weights(cfg: dict, key) -> Dict[str, jax.Array]:
-    """Seeded He-style weights for ``cfg`` (fan-in scaled normals)."""
-    k = cfg["kernel_size"]
-    cin = cfg["in_channels"]
-    params: Dict[str, jax.Array] = {}
-    if cfg["family"] == "cnn":
-        keys = jax.random.split(key, len(cfg["conv_channels"]) + 1)
-        for i, cout in enumerate(cfg["conv_channels"]):
-            params[f"conv{i}/w"] = _normal(keys[i], (k, k, cin, cout),
-                                           k * k * cin)
-            params[f"conv{i}/b"] = jnp.zeros((cout,), jnp.float32)
-            _bn(params, f"bn{i}", cout)
-            cin = cout
-    else:
-        keys = jax.random.split(key, 2 * len(cfg["blocks"]) + 2)
-        stem = cfg["stem_channels"]
-        params["stem/w"] = _normal(keys[0], (k, k, cin, stem), k * k * cin)
-        params["stem/b"] = jnp.zeros((stem,), jnp.float32)
-        cin = stem
-        for i, (cout, _) in enumerate(cfg["blocks"]):
-            params[f"dw{i}/w"] = _normal(keys[2 * i + 1], (k, k, 1, cin), k * k)
-            params[f"dw{i}/b"] = jnp.zeros((cin,), jnp.float32)
-            params[f"pw{i}/w"] = _normal(keys[2 * i + 2], (1, 1, cin, cout),
-                                         cin)
-            params[f"pw{i}/b"] = jnp.zeros((cout,), jnp.float32)
-            _bn(params, f"dw{i}_bn", cin)
-            _bn(params, f"pw{i}_bn", cout)
-            cin = cout
-    n = fc_in(cfg)
-    params["fc/w"] = _normal(keys[-1], (n, cfg["n_classes"]), n)
-    params["fc/b"] = jnp.zeros((cfg["n_classes"],), jnp.float32)
-    return params
 
 
 def _conv(x, w, b, stride: int = 1, groups: int = 1):
@@ -120,39 +116,6 @@ def _same(name, v):
     return v
 
 
-def _forward(cfg: dict, params, x, act):
-    """The model, with ``act(name, v)`` applied at every layer output.
-    Layer outputs: ``input``; ``conv<i>``
-    (conv + BN + ReLU, before its max-pool) for ``cnn``; ``stem`` (the stem
-    conv, before its max-pool and ReLU), ``dw<i>`` and ``pw<i>`` (conv + BN
-    + ReLU) for ``separable``; and ``logits``."""
-    p = cfg["pool"]
-    x = act("input", x)
-    if cfg["family"] == "cnn":
-        for i in range(len(cfg["conv_channels"])):
-            w, b = _folded(params, f"conv{i}", f"bn{i}")
-            x = act(f"conv{i}", jax.nn.relu(_conv(x, w, b)))
-            x = _maxpool(x, p)
-    else:
-        x = act("stem", _conv(x, params["stem/w"], params["stem/b"]))
-        x = jax.nn.relu(_maxpool(x, p))
-        for i, (_, s) in enumerate(cfg["blocks"]):
-            w, b = _folded(params, f"dw{i}", f"dw{i}_bn")
-            x = act(f"dw{i}", jax.nn.relu(
-                _conv(x, w, b, s, groups=x.shape[-1])))
-            w, b = _folded(params, f"pw{i}", f"pw{i}_bn")
-            x = act(f"pw{i}", jax.nn.relu(_conv(x, w, b)))
-    x = x.reshape(x.shape[0], -1)
-    return act("logits", x @ params["fc/w"] + params["fc/b"])
-
-
-def forward(cfg: dict, params: Dict[str, jax.Array], x) -> jax.Array:
-    """Float32 logits (B, n_classes) of images ``x`` (B, H, W, C).  Call it
-    under ``jax.default_matmul_precision("highest")``: on a TPU a float32
-    matmul or conv otherwise runs in bfloat16 passes."""
-    return _forward(cfg, params, x, _same)
-
-
 # -- the configuration's precision: D<act_bits> activations, W<bits> weights --
 
 def act_ranges(cfg: dict, params, calib) -> Dict[str, float]:
@@ -164,7 +127,7 @@ def act_ranges(cfg: dict, params, calib) -> Dict[str, float]:
         def act(name, v):
             seen[name] = jnp.max(jnp.abs(v))
             return v
-        _forward(cfg, p, x, act)
+        model(cfg).forward(cfg, p, x, act)
         return seen
     with jax.default_matmul_precision("highest"):
         out = jax.jit(maxima)(params, calib)
@@ -181,37 +144,25 @@ def act_fracs(cfg: dict, ranges: Dict[str, float]) -> Dict[str, int]:
             for name, r in ranges.items()}
 
 
-def quantized_weights(cfg: dict, params) -> Dict[str, tuple]:
-    """Per layer, ``(codes, scale, bias)`` at ``weight_bits``: batch-norm
-    folded in float64 then rounded to float32, then symmetric
-    per-output-channel codes, ``scale = max|w| / (2^(bits-1) - 1)``."""
+def weight_codes(cfg: dict, p: Dict[str, np.ndarray], layer: str,
+                 bn: str | None = None) -> tuple:
+    """``(codes, scale, bias)`` of ``layer`` at ``weight_bits`` from the
+    NumPy weights ``p``: batch-norm ``bn`` folded in float64 then rounded
+    to float32, then symmetric per-output-channel codes,
+    ``scale = max|w| / (2^(bits-1) - 1)``."""
     q = 2 ** (cfg["weight_bits"] - 1) - 1
-    p = {k: np.asarray(v) for k, v in params.items()}
-
-    def fold(layer, bn=None):
-        w, b = p[f"{layer}/w"], p[f"{layer}/b"]
-        if bn is not None:
-            inv = (p[f"{bn}/scale"].astype(np.float64)
-                   / np.sqrt(p[f"{bn}/var"].astype(np.float64) + 1e-5))
-            shift = p[f"{bn}/bias"] - p[f"{bn}/mean"] * inv
-            w = (w.astype(np.float64) * inv).astype(np.float32)
-            b = (b.astype(np.float64) * inv + shift).astype(np.float32)
-        s = np.maximum(np.abs(w).max(axis=tuple(range(w.ndim - 1)),
-                                     keepdims=True), np.float32(1e-8))
-        s = (s / np.float32(q)).astype(np.float32)
-        codes = np.clip(np.round(w / s), -q, q).astype(np.float32)
-        return codes, s.reshape(-1), b
-
-    out = {"fc": fold("fc")}
-    if cfg["family"] == "cnn":
-        for i in range(len(cfg["conv_channels"])):
-            out[f"conv{i}"] = fold(f"conv{i}", f"bn{i}")
-    else:
-        out["stem"] = fold("stem")
-        for i in range(len(cfg["blocks"])):
-            out[f"dw{i}"] = fold(f"dw{i}", f"dw{i}_bn")
-            out[f"pw{i}"] = fold(f"pw{i}", f"pw{i}_bn")
-    return out
+    w, b = p[f"{layer}/w"], p[f"{layer}/b"]
+    if bn is not None:
+        inv = (p[f"{bn}/scale"].astype(np.float64)
+               / np.sqrt(p[f"{bn}/var"].astype(np.float64) + 1e-5))
+        shift = p[f"{bn}/bias"] - p[f"{bn}/mean"] * inv
+        w = (w.astype(np.float64) * inv).astype(np.float32)
+        b = (b.astype(np.float64) * inv + shift).astype(np.float32)
+    s = np.maximum(np.abs(w).max(axis=tuple(range(w.ndim - 1)),
+                                 keepdims=True), np.float32(1e-8))
+    s = (s / np.float32(q)).astype(np.float32)
+    codes = np.clip(np.round(w / s), -q, q).astype(np.float32)
+    return codes, s.reshape(-1), b
 
 
 def grid_steps(fracs: Dict[str, int]) -> Dict[str, tuple]:
@@ -233,39 +184,36 @@ def _dot_exact(x, w):
     return acc.astype(jnp.float32)
 
 
-def quantized_forward(cfg: dict, qw: Dict[str, tuple], x, steps):
-    """Logits of the model at the configuration's precision, in the integer
-    code domain: the input and every layer output are ``act_bits`` codes on
-    the power-of-two grids ``steps`` (:func:`grid_steps`); each layer
-    accumulates codes x weight codes exactly, scales the sum by
-    ``weight scale x input step``, adds its bias, applies its ReLU and
-    rounds (half to even) and saturates to its output grid.  Max-pool and
-    ReLU act on codes.  ``qw`` is :func:`quantized_weights`.  Call it under
-    HIGHEST matmul precision: then every float32 sum of codes is exact."""
-    lo, hi = -2 ** (cfg["act_bits"] - 1), 2 ** (cfg["act_bits"] - 1) - 1
-    p = cfg["pool"]
+class Codes:
+    """The integer code domain of ``cfg`` with the layer codes ``qw``
+    (:func:`quantized_weights`) and the grids ``steps``
+    (:func:`grid_steps`).  The encode rule: a value goes onto its layer
+    output's ``act_bits`` grid rounded half to even and saturated.  A layer
+    accumulates codes x weight codes exactly, scales the sum by ``weight
+    scale x input step``, adds its bias, applies its ReLU (if any) and is
+    encoded.  Max-pool and ReLU act on codes."""
 
-    def encode(v, name):
-        return jnp.clip(jnp.round(v * steps[name][1]), lo, hi)
+    def __init__(self, cfg: dict, qw: Dict[str, tuple], steps):
+        self.lo = -2 ** (cfg["act_bits"] - 1)
+        self.hi = 2 ** (cfg["act_bits"] - 1) - 1
+        self.qw, self.steps = qw, steps
 
-    def layer(c, src, name, relu, stride=1, groups=1):
-        codes, scale, bias = qw[name]
+    def encode(self, v, name: str):
+        return jnp.clip(jnp.round(v * self.steps[name][1]), self.lo, self.hi)
+
+    def layer(self, c, src: str, name: str, relu: bool, stride: int = 1,
+              groups: int = 1):
+        """Conv layer ``name`` over the codes ``c`` of layer output
+        ``src``."""
+        codes, scale, bias = self.qw[name]
         acc = _conv(c, codes, 0.0, stride, groups)
-        y = acc * (scale * steps[src][0]) + bias
-        return encode(jax.nn.relu(y) if relu else y, name)
+        y = acc * (scale * self.steps[src][0]) + bias
+        return self.encode(jax.nn.relu(y) if relu else y, name)
 
-    c, src = encode(x, "input"), "input"
-    if cfg["family"] == "cnn":
-        for i in range(len(cfg["conv_channels"])):
-            c, src = layer(c, src, f"conv{i}", True), f"conv{i}"
-            c = _maxpool(c, p)
-    else:
-        c, src = layer(c, src, "stem", False), "stem"
-        c = jax.nn.relu(_maxpool(c, p))
-        for i, (_, s) in enumerate(cfg["blocks"]):
-            c, src = layer(c, src, f"dw{i}", True, s, c.shape[-1]), f"dw{i}"
-            c, src = layer(c, src, f"pw{i}", True), f"pw{i}"
-    codes, scale, bias = qw["fc"]
-    acc = _dot_exact(c.reshape(c.shape[0], -1), codes)
-    y = encode(acc * (scale * steps[src][0]) + bias, "logits")
-    return y * steps["logits"][0]
+    def logits(self, c, src: str):
+        """The ``fc`` layer over the flattened codes ``c`` of ``src``, its
+        ``logits`` codes decoded to float."""
+        codes, scale, bias = self.qw["fc"]
+        acc = _dot_exact(c.reshape(c.shape[0], -1), codes)
+        y = self.encode(acc * (scale * self.steps[src][0]) + bias, "logits")
+        return y * self.steps["logits"][0]

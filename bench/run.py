@@ -10,7 +10,11 @@ A cell (``BENCHMARK.json`` ``workloads``) names a configuration
    the seed (one jitted call, on the device), ``DesignFlow`` to the ``qjax``
    target calibrated on 16 seeded rows, the ``AccelServer`` from
    ``serve_adaptive`` pinned to the mix's working point, every bucket's
-   program called once, one request of each size sent through the server;
+   program called once, one request of each size sent through the server.
+   The program's span recorder (``repro.spans``) is on from the program's
+   reader until every bucket's program has been called, and off before the
+   server starts: the record's ``program`` holds its counters and the
+   seconds of each span name, and the window runs with the recorder off;
 2. the window: ``--seconds`` of the mix's load, open or closed loop,
    driving ``AccelServer.submit`` and timing every request on the client
    side (open loop: from when it was due);
@@ -28,12 +32,14 @@ neither costs the window anything.  The trace's numbers cover the middle
 and ``mfu_int8``'s rate cover the whole window.  Each metric is read
 by ``bench/metrics/<name>.py`` from the run's record; a metric split by the
 end-to-end metric it moves (``batch_rows.open``, ``batch_rows.sat``) may
-share ``bench/metrics/batch_rows.py``.  The last line of standard output is
-one JSON object; the compared numbers and their limits are the last lines
-of standard error.  A run is correct when every request of the window was
-answered (``failed_requests`` 0) and the sampled answers agree with the
-reference (:mod:`check`).  With no TPU, or fewer chips than the cell asks
-for, the run exits with code 2 and prints no result.
+share ``bench/metrics/batch_rows.py``.  A configuration's file, its model
+module and a kernel's operation count are found by name too (:mod:`hooks`).
+The last line of standard output is one JSON object; the compared numbers
+and their limits are the last lines of standard error.  A run is correct
+when every request of the window was answered (``failed_requests`` 0) and
+the sampled answers agree with the reference (:mod:`check`).  With no TPU,
+or fewer chips than the cell asks for, the run exits with code 2 and
+prints no result.
 """
 from __future__ import annotations
 
@@ -44,7 +50,6 @@ T_START = time.perf_counter()       # set-up is timed from here
 import argparse  # noqa: E402
 import contextlib  # noqa: E402
 import gc  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import queue  # noqa: E402
 import shutil  # noqa: E402
@@ -60,10 +65,10 @@ BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 sys.path.insert(0, str(BENCH))
 
+import hooks  # noqa: E402
 import traffic  # noqa: E402
 
 RESULT_WAIT_S = 60.0     # how long past the window's close an answer may take
-KERNELS = ("qgemm_kernel", "qconv_dw_kernel")
 TRACE_SECONDS = 2.0      # the traced part of a --trace 1 window, mid-window
 COMPILE_EVENTS = ("/jax/core/compile/jaxpr_to_mlir_module_duration",
                   "/jax/core/compile/backend_compile_duration")
@@ -92,7 +97,10 @@ def cell_spec(bench: dict, name: str) -> dict:
 
 
 def config(name: str) -> dict:
-    return load_json(BENCH / "configs" / f"{name}.json")
+    path = hooks.path("configs", name, ".json")
+    if path is None:
+        raise KeyError(f"no configuration file {name}.json")
+    return load_json(path)
 
 
 def metrics_for(bench: dict, cell: str, kind: str) -> list:
@@ -106,13 +114,11 @@ def reader(name: str):
     """``read(record)`` of ``bench/metrics/<name>.py``, else of the reader
     of the name without its last ``.`` part (``batch_rows.open`` ->
     ``batch_rows.py``)."""
-    path = BENCH / "metrics" / f"{name}.py"
-    if not path.exists() and "." in name:
-        path = BENCH / "metrics" / f"{name.rsplit('.', 1)[0]}.py"
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
+    mod = hooks.module("metrics", name)
+    if mod is None and "." in name:
+        mod = hooks.module("metrics", name.rsplit(".", 1)[0])
+    if mod is None:
+        raise KeyError(f"no reader bench/metrics/{name}.py")
     return mod.read
 
 
@@ -366,6 +372,17 @@ def _counters(srv) -> dict:
                                      "scheduled_batches", "misses")}
 
 
+def _program_record(snap: dict) -> dict:
+    """The program's counters and the total seconds of each span name its
+    recorder kept in set-up, and how many spans it dropped."""
+    spans_s: dict = {}
+    for s in snap["spans"]:
+        t = (s.end_ns - s.start_ns) / 1e9
+        spans_s[s.name] = spans_s.get(s.name, 0.0) + t
+    return {"counters": snap["counters"], "spans_s": spans_s,
+            "dropped": snap["dropped"]}
+
+
 def _rates(rec: dict, part: TracedPart, seconds: float) -> dict:
     """Images answered per second over the whole window, and inside the
     part of it the trace is reduced over."""
@@ -417,19 +434,23 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool, *,
     shape = program.image_shape(cfg)
     params = program.make_weights(cfg, seed)
     calib = traffic.calibration_rows(shape, seed)
-    result = program.build(cfg, params, calib)
-    srv = program.serve(result, point, mix["server"])
-    exe = srv.point_executables[point]
     buckets = sorted(set(mix["server"]["buckets"])
                      | {mix["server"]["max_batch"]})
     kernels_by_sig = {}
-    for b in buckets:                   # every bucket's program, compiled
-        x = np.zeros((b, *shape), np.float32)
-        np.asarray(exe(x))
-        if trace:
-            from work import lowered_kernels
-            kernels_by_sig.update(lowered_kernels(
-                exe.executable_for(x).lower(x).as_text(), KERNELS))
+    program.spans.enable()
+    try:
+        result = program.build(cfg, params, calib)
+        srv = program.serve(result, point, mix["server"])
+        exe = srv.point_executables[point]
+        for b in buckets:               # every bucket's program, compiled
+            x = np.zeros((b, *shape), np.float32)
+            np.asarray(exe(x))
+            if trace:
+                from work import lowered_kernels
+                kernels_by_sig.update(lowered_kernels(
+                    exe.executable_for(x).lower(x).as_text()))
+    finally:
+        program_rec = _program_record(program.spans.disable().snapshot())
     if trace:
         exe = Spanned(exe, span)
     if wrap is not None:
@@ -478,7 +499,8 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool, *,
               "memory_peak_bytes": int(mem.get("peak_bytes_in_use", 0))}
     rec.update(cell=cell, setup_s=setup_s, window_s=seconds, chips=spec["chips"],
                stats={k: after[k] - before[k] for k in after},
-               gc_pauses_s=[d for _, d in gc_pauses.pauses])
+               gc_pauses_s=[d for _, d in gc_pauses.pauses],
+               program=program_rec)
     if part is not None:
         import trace as trace_mod
         from work import event_call, useful_ops_per_image

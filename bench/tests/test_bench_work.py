@@ -16,7 +16,6 @@ import work  # noqa: E402
 
 DATA = BENCH / "tests" / "data"
 PEAKS = {"int8_ops_per_s": 393e12, "hbm_bytes_per_s": 819e9}
-KERNELS = ("qgemm_kernel", "qconv_dw_kernel")
 
 
 def _config(name):
@@ -45,8 +44,7 @@ def test_useful_ops_mobilenet():
 
 @pytest.fixture(scope="module")
 def lowered():
-    return work.lowered_kernels((DATA / "lowered_calls.txt").read_text(),
-                                KERNELS)
+    return work.lowered_kernels((DATA / "lowered_calls.txt").read_text())
 
 
 def test_lowered_kernels_named(lowered):

@@ -9,7 +9,8 @@ Two counts, for two different questions:
 * :func:`event_call` — the operations and least bytes of one Pallas kernel
   call, from that call's operand and result shapes in the served program's
   HLO (the device trace names each operation by its HLO instruction), and
-  the kernel's name from the lowered program (:func:`lowered_kernels`).
+  the kernel's name from the lowered program (:func:`lowered_kernels`), by
+  the kernel's own count file, ``bench/kernels/<kernel>.py`` (:mod:`hooks`).
   They follow whatever padding and tiling the program chose, so a kernel's
   roofline share says how close the kernel came to the chip's limits on the
   work it was given.
@@ -18,33 +19,16 @@ from __future__ import annotations
 
 import re
 from dataclasses import dataclass
-from math import prod
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from reference import fc_in
+import hooks
+import reference
 
 
 def useful_ops_per_image(cfg: dict) -> int:
     """2 x the multiply-accumulates of every conv and FC layer of ``cfg``
-    for one image (SAME padding: output size is ceil(input / stride))."""
-    h, w = cfg["image_hw"]
-    k = cfg["kernel_size"]
-    cin = cfg["in_channels"]
-    macs = 0
-    if cfg["family"] == "cnn":
-        for cout in cfg["conv_channels"]:
-            macs += h * w * k * k * cin * cout
-            h, w, cin = h // cfg["pool"], w // cfg["pool"], cout
-    else:
-        macs += h * w * k * k * cin * cfg["stem_channels"]
-        h, w, cin = h // cfg["pool"], w // cfg["pool"], cfg["stem_channels"]
-        for cout, s in cfg["blocks"]:
-            h, w = -(-h // s), -(-w // s)
-            macs += h * w * k * k * cin          # depthwise
-            macs += h * w * cin * cout           # pointwise
-            cin = cout
-    macs += fc_in(cfg) * cfg["n_classes"]
-    return 2 * macs
+    for one image, from its widths alone (its model module's count)."""
+    return reference.model(cfg).useful_ops_per_image(cfg)
 
 
 # bytes per element of the element types a kernel call can carry, under
@@ -54,6 +38,7 @@ _ELEM_BYTES = {"i8": 1, "ui8": 1, "s8": 1, "u8": 1, "i16": 2, "s16": 2,
                "f32": 4}
 _STABLEHLO = re.compile(r"tensor<((?:[0-9]+x)*)([a-z]+[0-9]+)>")
 _HLO = re.compile(r"\b([a-z]+[0-9]+)\[([0-9,]*)\]")
+_KERNEL_NAME = re.compile(r'kernel_name = "([^"]+)"')
 
 Shape = Tuple[Tuple[int, ...], int]     # (dims, bytes per element)
 
@@ -77,34 +62,15 @@ def _hlo_shapes(text: str) -> List[Shape]:
             for t, dims in _HLO.findall(text)]
 
 
-def _views(operands: Sequence[Shape]) -> int:
-    """How many leading operands are the same buffer (a depthwise call's
-    window-row views of one activation array)."""
-    n = 1
-    while n < len(operands) and operands[n] == operands[0]:
-        n += 1
-    return n
-
-
 def count(kernel: str, operands: Sequence[Shape],
-          result: Sequence[Shape]) -> KernelCall:
-    """Operations and least bytes of one call from its shapes.
-
-    ``qgemm_kernel``: operand 0 is the (M, K) activation tile and the result
-    (M, N): 2*M*K*N operations.  ``qconv_dw_kernel``: the first ``kh``
-    operands are views of one padded activation array (one per window row,
-    counted once in the bytes), the result holds every output element of
-    every channel, and the window is square: 2 * |result| * kh * kh."""
-    if kernel == "qgemm_kernel":
-        (m, k), (_, n) = operands[0][0], result[0][0]
-        ops, skip = 2 * m * k * n, 0
-    elif kernel == "qconv_dw_kernel":
-        kh = _views(operands)
-        ops, skip = 2 * prod(result[0][0]) * kh * kh, kh - 1
-    else:
-        raise ValueError(f"no operation count for kernel {kernel!r}")
-    nbytes = sum(prod(d) * b for d, b in operands[skip:])
-    nbytes += sum(prod(d) * b for d, b in result)
+          result: Sequence[Shape]) -> Optional[KernelCall]:
+    """Operations and least bytes of one call of ``kernel`` from its shapes,
+    by ``bench/kernels/<kernel>.py``'s ``count``; None for a kernel with no
+    such file, which is then not counted."""
+    mod = hooks.module("kernels", kernel)
+    if mod is None:
+        return None
+    ops, nbytes = mod.count(operands, result)
     return KernelCall(kernel, ops, nbytes)
 
 
@@ -112,20 +78,20 @@ def signature(operands: Sequence[Shape], result: Sequence[Shape]) -> tuple:
     return tuple(operands), tuple(result)
 
 
-def lowered_kernels(hlo_text: str, kernels: Sequence[str]) -> Dict[tuple, str]:
-    """``{call signature: kernel name}`` of every call of the named Pallas
-    kernels in the StableHLO text of a lowered program
-    (``jax.jit(f).lower(x).as_text()``), where each call names its kernel."""
+def lowered_kernels(hlo_text: str) -> Dict[tuple, str]:
+    """``{call signature: kernel name}`` of every call of a Pallas kernel
+    that has a count file (``bench/kernels/<kernel>.py``) in the StableHLO
+    text of a lowered program (``jax.jit(f).lower(x).as_text()``), where
+    each call names its kernel."""
     out = {}
     for line in hlo_text.splitlines():
-        if "tpu_custom_call" not in line:
-            continue
-        name = next((k for k in kernels if f'kernel_name = "{k}"' in line),
-                    None)
-        if name is None:
+        name = _KERNEL_NAME.search(line)
+        if ("tpu_custom_call" not in line or name is None
+                or hooks.path("kernels", name.group(1)) is None):
             continue
         args, res = line[line.rindex(": (") + 3:].split(") -> ", 1)
-        out[signature(_stablehlo_shapes(args), _stablehlo_shapes(res))] = name
+        out[signature(_stablehlo_shapes(args),
+                      _stablehlo_shapes(res))] = name.group(1)
     return out
 
 
